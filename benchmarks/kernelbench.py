@@ -66,7 +66,7 @@ def run(csv: bool = True):
     ref_fn = jax.jit(lambda t, i: ref.embedding_bag_ref(t, i))
     us = _time(ref_fn, table, idx)
     rows.append(("xla_gather_ref", us))
-    interp = jax.default_backend() != "tpu"
+    interp = compat.pallas_interpret()
     tag = "_interpret" if interp else ""
     for strat in Strategy:
         fn = jax.jit(
@@ -107,7 +107,7 @@ def crossover_sweep(csv: bool = True) -> dict:
 
     model = analytic_model()
     block_r = 512
-    interp = jax.default_backend() != "tpu"
+    interp = compat.pallas_interpret()
     cells = []
     for rows in (1024, 32_768):
         for batch in (64, 512):

@@ -59,6 +59,8 @@ import numpy as np
 
 from repro.core.strategies import ALL_STRATEGIES, Strategy
 from repro.core.tables import TableSpec
+from repro.compat import vmem_bytes
+from repro.kernels.embedding_l1 import PIN_VMEM_BYTES
 
 __all__ = [
     "A100",
@@ -86,7 +88,7 @@ KERNEL_PATHS = ("onehot", "sparse")
 _SPARSE_GATHER_OVERHEAD = 2e-9
 _SPARSE_STEP_OVERHEAD = 5e-8
 # nominal fused-kernel row-block when the caller doesn't know the pack's
-# (matches partition._RAGGED_BLOCK_R)
+# (partition._RAGGED_BLOCK_RS[0])
 _NOMINAL_BLOCK_R = 512
 
 
@@ -148,7 +150,10 @@ A100 = HardwareSpec(
 )
 
 # TPU v5e: 1 core/chip, 197 TFLOP/s bf16 MXU, 819 GB/s HBM, 128 MB VMEM.
-# We budget half of VMEM for persistent tables (the rest feeds the pipeline).
+# l1_bytes is the planner's logical per-core budget (fused-kernel "L1"
+# chunks stream like GM ones); a table pinned whole by the symmetric
+# group's L1 kernels must also fit their PIN_VMEM_BYTES once padded to 128
+# lanes (CostModel.fits_l1).
 TPU_V5E = HardwareSpec(
     name="tpu_v5e",
     cores=1,
@@ -282,8 +287,16 @@ class CostModel:
         return strat, cost
 
     def fits_l1(self, table: TableSpec, rows: int | None = None) -> bool:
+        """Whether a whole table may take an L1 strategy in the symmetric
+        group: its logical bytes fit ``l1_bytes`` and the L1 kernels' padded
+        VMEM copy (rows plus the zero row, f32 — the widest dtype the pack
+        takes) fits their ``PIN_VMEM_BYTES``, which they enforce on every
+        backend."""
         rows = table.rows if rows is None else rows
-        return rows * table.row_bytes <= self.hardware.l1_bytes
+        return (
+            rows * table.row_bytes <= self.hardware.l1_bytes
+            and vmem_bytes((rows + 1, table.dim)) <= PIN_VMEM_BYTES
+        )
 
     def cross_host_time(self, nbytes: float, hosts: int = 2) -> float:
         """Modeled wall time of the two-level mesh's one cross-host

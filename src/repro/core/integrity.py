@@ -147,7 +147,6 @@ class IntegrityManifest:
         chunk = np.array(packed.chunk_data)
         cache = np.array(packed.cache_data) if packed.cache_rows else None
         sym = np.array(packed.sym_data)
-        sym_table = np.asarray(packed.sym_table)
         per_core = plan.per_core()
         healed: list[tuple] = []
         quarantined: list[tuple] = []
@@ -183,7 +182,7 @@ class IntegrityManifest:
                         chunk[core, s, : a.rows] = rows
                 (healed if table_data is not None else quarantined).append(key)
             elif kind == "sym":
-                ti = int(sym_table[core])
+                ti = packed.sym_static[core][0]
                 sym[core] = 0
                 rows = src(ti, 0, tables[ti].rows)
                 if rows is not None:

@@ -28,7 +28,9 @@ rendering (DESIGN.md §3–§4, the single-pass streaming executor):
   to the placed slots, not K·N·B·E.  ``reduce_mode="psum"`` (the paper's
   atomic accumulation) and ``"ring"`` are kept;
 * the LIF symmetric fallback group executes batch-split over the same axis and
-  rejoins with an ``all_gather``.
+  rejoins with an ``all_gather``; each symmetric table's kernel is fixed at
+  pack time (``PackedPlan.sym_static``), so only the kernel its strategy
+  names is compiled for it.
 
 Each chunk's region in the ragged buffer is padded to a ``block_r`` multiple
 with at least one zero row after the data, and the buffer carries one shared
@@ -46,8 +48,7 @@ The ``use_kernels`` / ``reduce_mode`` contract (single source of truth —
 * ``use_kernels=False`` — the XLA gather path: identical math, no Pallas
   (the CPU-fast correctness oracle);
 * ``use_kernels=True`` — deprecated spelling of the retired per-slot scan:
-  warns and routes ragged plans to ``"fused"`` (``layout="dense"`` keeps the
-  legacy stacked-slot scan, for comparison benchmarks only);
+  warns and routes to ``"fused"``;
 * ``reduce_mode`` ∈ {``"sparse"`` (default owner-sharded all_to_all +
   all_gather rejoin), ``"psum"`` (the paper's atomic accumulation),
   ``"ring"`` (collective-permute pipelined accumulation)} — all three are
@@ -114,6 +115,7 @@ __all__ = [
     "cache_plan_entries",
     "pack_plan",
     "partitioned_lookup",
+    "place_packed",
     "vocab_parallel_embed",
 ]
 
@@ -123,10 +125,14 @@ STRATEGY_CODE: dict[Strategy, int] = {
     Strategy.L1: 2,
     Strategy.L1_UB: 3,
 }
+_CODE_STRATEGY = {v: k for k, v in STRATEGY_CODE.items()}
 
 _ROW_PAD = 8  # sublane-friendly row padding
-_RAGGED_BLOCK_R = 512  # row-block cap for the ragged fused-kernel schedule
-_RAGGED_BLOCK_R_MIN = 64  # floor: bounds step count; wastes < 64 rows/chunk
+# ragged fused-kernel row-block candidates, largest first.  Bigger blocks
+# mean fewer steps, and the step schedule is scalar-prefetched into the
+# core's SMEM (1 MiB on v5e); smaller ones waste fewer padding rows.
+_RAGGED_BLOCK_RS = (512, 256, 128, 64)
+_RAGGED_PAD_SLACK = 1.125  # padded rows allowed per data row (incl. zero row)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -167,10 +173,7 @@ class PackedPlan:
     rejoin_owned_pos: Any  # (N,) int32 bucket position at the owner, -1
     rejoin_bucket: Any  # (K, O) int32 owned table ids, -1 pad
     # symmetric fallback group (replicated)
-    sym_data: Any  # (Nsym, Msym+1, E)
-    sym_table: Any  # (Nsym,) int32
-    sym_rows: Any  # (Nsym,) int32
-    sym_strategy: Any  # (Nsym,) int32
+    sym_data: Any  # (Nsym, Msym+1, E); the tables are in sym_static
     # hot-row residency cache (ragged layout; zero-sized when off)
     cache_data: Any = None  # (K, C, E) per-core resident hot-row mini-table
     cache_remap: Any = None  # (K, T+1) int32 buffer row -> cache pos, -1 cold
@@ -182,6 +185,9 @@ class PackedPlan:
     unique_cap: int = 0  # batch-dedup width per slot; 0 = dedup off
     cache_rows: int = 0  # padded residency-cache rows; 0 = cache off
     kernel_path: str = "onehot"  # resolved gather mode; "onehot" = no sparse
+    # per symmetric table (table id, rows, strategy code), static so each
+    # table compiles only its own strategy's kernel
+    sym_static: tuple = ()
 
     _ARRAY_FIELDS = (
         "chunk_data", "slot_table", "slot_offset", "slot_rows",
@@ -189,13 +195,11 @@ class PackedPlan:
         "step_slot", "step_base", "step_block", "step_strategy",
         "step_kpath",
         "rejoin_send", "rejoin_owned_pos", "rejoin_bucket",
-        "sym_data", "sym_table", "sym_rows", "sym_strategy",
-        "cache_data", "cache_remap",
+        "sym_data", "cache_data", "cache_remap",
     )
     # replicated across the core axis (everything else is core-sharded)
     _REPLICATED_FIELDS = (
-        "rejoin_send", "rejoin_owned_pos", "rejoin_bucket",
-        "sym_data", "sym_table", "sym_rows", "sym_strategy",
+        "rejoin_send", "rejoin_owned_pos", "rejoin_bucket", "sym_data",
     )
 
     def tree_flatten(self):
@@ -203,6 +207,7 @@ class PackedPlan:
         aux = (
             self.layout, self.block_r, self.slot_window, self.block_b,
             self.unique_cap, self.cache_rows, self.kernel_path,
+            self.sym_static,
         )
         return children, aux
 
@@ -233,6 +238,41 @@ class PackedPlan:
 
 def _align(n: int, mult: int) -> int:
     return int(-(-n // mult) * mult)
+
+
+def _default_block_r(rows: Sequence[int]) -> int:
+    """Largest row-block whose per-chunk padding (each chunk plus its zero
+    row rounded up to a block) keeps the buffer within
+    ``_RAGGED_PAD_SLACK`` of its data rows."""
+    data = sum(r + 1 for r in rows)
+    for br in _RAGGED_BLOCK_RS[:-1]:
+        if sum(_align(r + 1, br) for r in rows) <= _RAGGED_PAD_SLACK * data:
+            return br
+    return _RAGGED_BLOCK_RS[-1]
+
+
+def place_packed(
+    packed: "PackedPlan", mesh: jax.sharding.Mesh, axis: str = "model"
+) -> "PackedPlan":
+    """Put each packed array where :func:`partitioned_lookup` runs it:
+    core-sharded fields split over the mesh's ``axis`` (core ``c``'s slice
+    on the ``c``-th device), replicated fields on every device."""
+    pspec = jax.sharding.PartitionSpec
+
+    def put(f):
+        spec = pspec() if f in PackedPlan._REPLICATED_FIELDS else pspec(axis)
+        return jax.device_put(
+            getattr(packed, f), jax.sharding.NamedSharding(mesh, spec)
+        )
+
+    return dataclasses.replace(
+        packed,
+        **{
+            f: put(f)
+            for f in PackedPlan._ARRAY_FIELDS
+            if getattr(packed, f) is not None
+        },
+    )
 
 
 def _rejoin_maps(
@@ -537,14 +577,7 @@ def pack_plan(
         # ragged: per core, concatenate chunks row-wise; each chunk's region
         # is padded to a block_r multiple (>= 1 zero row after the data, the
         # slot's redirect target), so the fused kernel's row-blocks tile it.
-        # block_r is sized off the SMALLEST real chunk: the quantum bounds
-        # each chunk's padding, while big chunks just take more steps (cheap:
-        # the steps are the streaming DMAs the kernel does anyway).
-        min_rows = min((a.rows for a in plan.assignments), default=1)
-        br = block_r or min(
-            _RAGGED_BLOCK_R,
-            max(_align(min_rows + 1, _ROW_PAD), _RAGGED_BLOCK_R_MIN),
-        )
+        br = block_r or _default_block_r([a.rows for a in plan.assignments])
         br = max(_align(br, _ROW_PAD), _ROW_PAD)
         # per-strategy step schedule: slots grouped by strategy code (then
         # ascending size) so every strategy's steps form one contiguous run —
@@ -727,16 +760,8 @@ def pack_plan(
             t = tbl(i)
             sym_blocks.append(jnp.pad(t, ((0, msym + 1 - t.shape[0]), (0, 0))))
         sym_data = jnp.stack(sym_blocks)
-        sym_table = np.array(sym_idx, np.int32)
-        sym_rows = np.array([tables[i].rows for i in sym_idx], np.int32)
-        sym_strategy = np.array(
-            [STRATEGY_CODE[s] for s in plan.symmetric_strategies], np.int32
-        )
     else:
         sym_data = jnp.zeros((0, 1, e), dtype)
-        sym_table = np.zeros((0,), np.int32)
-        sym_rows = np.zeros((0,), np.int32)
-        sym_strategy = np.zeros((0,), np.int32)
 
     return PackedPlan(
         chunk_data=chunk_arr,
@@ -756,9 +781,6 @@ def pack_plan(
         rejoin_owned_pos=jnp.asarray(rejoin_owned_pos),
         rejoin_bucket=jnp.asarray(rejoin_bucket),
         sym_data=sym_data,
-        sym_table=jnp.asarray(sym_table),
-        sym_rows=jnp.asarray(sym_rows),
-        sym_strategy=jnp.asarray(sym_strategy),
         cache_data=cache_data,
         cache_remap=cache_remap,
         layout=layout,
@@ -768,6 +790,10 @@ def pack_plan(
         unique_cap=int(unique_cap),
         cache_rows=int(cache_rows),
         kernel_path=kernel_resolved,
+        sym_static=tuple(
+            (i, tables[i].rows, STRATEGY_CODE[s])
+            for i, s in zip(sym_idx, plan.symmetric_strategies)
+        ),
     )
 
 
@@ -777,20 +803,22 @@ def pack_plan(
 
 
 def _bag_with_strategy(
-    chunk: jax.Array, lidx: jax.Array, strategy_code: jax.Array, use_kernels: bool
+    chunk: jax.Array, lidx: jax.Array, strategy_code: int, use_kernels: bool
 ) -> jax.Array:
-    """(R+1, E) chunk x (B, s) pre-clipped local indices -> (B, E) f32."""
+    """(R+1, E) chunk x (B, s) pre-clipped local indices -> (B, E) f32, with
+    the kernel of the static ``strategy_code``."""
     if not use_kernels:
         # XLA gather path: identical math; strategies only differ in timing.
         return jnp.take(chunk, lidx, axis=0).astype(jnp.float32).sum(axis=1)
-    interp = jax.default_backend() != "tpu"
-    branches = [
-        lambda c, i: embedding_bag_gm(c, i, interpret=interp),
-        lambda c, i: embedding_bag_ub(c, i, persistent=False, interpret=interp),
-        lambda c, i: embedding_bag_l1(c, i, interpret=interp),
-        lambda c, i: embedding_bag_ub(c, i, persistent=True, interpret=interp),
-    ]
-    return lax.switch(strategy_code, branches, chunk, lidx)
+    interp = compat.pallas_interpret()
+    strategy = _CODE_STRATEGY[strategy_code]
+    if strategy is Strategy.GM:
+        return embedding_bag_gm(chunk, lidx, interpret=interp)
+    if strategy is Strategy.L1:
+        return embedding_bag_l1(chunk, lidx, interpret=interp)
+    return embedding_bag_ub(
+        chunk, lidx, persistent=strategy is Strategy.L1_UB, interpret=interp
+    )
 
 
 # --------------------------------------------------------------------------
@@ -812,15 +840,12 @@ def _local_asym_lookup(
     ``use_kernels``: False = XLA gather; "fused" = ONE schedule-driven
     streaming pallas_call for the whole sweep (the default executor).
     ``True`` is the retired per-slot scan spelling — it routes to the fused
-    path for the ragged layout (no O(S·R_max·E) window is ever allocated)
-    and to the legacy stacked-slot scan for ``layout="dense"``.
+    path.
     """
-    if use_kernels == "fused" or (use_kernels and packed.layout != "dense"):
+    if use_kernels:
         return _fused_asym_lookup(packed, indices, n_tables=n_tables)
     if packed.layout == "dense":
-        return _dense_asym_lookup(
-            packed, indices, n_tables=n_tables, use_kernels=use_kernels
-        )
+        return _dense_asym_lookup(packed, indices, n_tables=n_tables)
 
     _, b, _ = indices.shape
     buffer = packed.chunk_data  # (T+1, E)
@@ -856,23 +881,23 @@ def _local_asym_lookup(
 
 
 def _dense_asym_lookup(
-    packed: PackedPlan, indices: jax.Array, *, n_tables: int, use_kernels
+    packed: PackedPlan, indices: jax.Array, *, n_tables: int
 ) -> jax.Array:
-    """Legacy stacked-slot sweep over (S, R+1, E) chunk_data."""
+    """XLA gather sweep over the legacy stacked-slot (S, R+1, E) layout."""
     _, b, _ = indices.shape
     rpad = packed.chunk_data.shape[-2] - 1  # zero row index
     e = packed.chunk_data.shape[-1]
     bpos = jnp.arange(b, dtype=jnp.int32)
 
     def body(out, xs):
-        chunk, ti, off, rows, strat, rep, nrep = xs
+        chunk, ti, off, rows, rep, nrep = xs
         idx = jnp.take(indices, jnp.maximum(ti, 0), axis=0)  # (B, s)
         local = idx - off
         valid = (idx >= 0) & (local >= 0) & (local < rows) & (ti >= 0)
         bmask = (bpos * nrep) // b == rep
         valid = valid & bmask[:, None]
         lidx = jnp.where(valid, local, rpad).astype(jnp.int32)
-        pooled = _bag_with_strategy(chunk, lidx, strat, use_kernels)
+        pooled = jnp.take(chunk, lidx, axis=0).astype(jnp.float32).sum(axis=1)
         out = out.at[jnp.maximum(ti, 0)].add(
             jnp.where(ti >= 0, pooled, jnp.zeros_like(pooled))
         )
@@ -884,7 +909,6 @@ def _dense_asym_lookup(
         packed.slot_table,
         packed.slot_offset,
         packed.slot_rows,
-        packed.slot_strategy,
         packed.slot_rep,
         packed.slot_nrep,
     )
@@ -895,25 +919,20 @@ def _dense_asym_lookup(
 def _local_sym_lookup(
     packed: PackedPlan, idx_slice: jax.Array, *, n_tables: int, use_kernels
 ) -> jax.Array:
-    """Symmetric fallback: idx_slice (N, B/K, s) -> (N, B/K, E) f32."""
-    n_sym = packed.sym_data.shape[0]
+    """Symmetric fallback: idx_slice (N, B/K, s) -> (N, B/K, E) f32.
+
+    One kernel call per symmetric table, on that table's own rows plus the
+    zero row after them (``sym_data`` pads every table to the largest)."""
     _, bl, _ = idx_slice.shape
     e = packed.sym_data.shape[-1]
-    out0 = jnp.zeros((n_tables, bl, e), jnp.float32)
-    if n_sym == 0:
-        return out0
-    rpad = packed.sym_data.shape[1] - 1
-
-    def body(out, xs):
-        tbl, ti, rows, strat = xs
-        idx = jnp.take(idx_slice, ti, axis=0)
-        valid = (idx >= 0) & (idx < rows)
-        lidx = jnp.where(valid, idx, rpad).astype(jnp.int32)
-        pooled = _bag_with_strategy(tbl, lidx, strat, bool(use_kernels))
-        return out.at[ti].add(pooled), None
-
-    xs = (packed.sym_data, packed.sym_table, packed.sym_rows, packed.sym_strategy)
-    out, _ = lax.scan(body, out0, xs)
+    out = jnp.zeros((n_tables, bl, e), jnp.float32)
+    for j, (ti, rows, code) in enumerate(packed.sym_static):
+        idx = idx_slice[ti]
+        lidx = jnp.where((idx >= 0) & (idx < rows), idx, rows).astype(jnp.int32)
+        pooled = _bag_with_strategy(
+            packed.sym_data[j, : rows + 1], lidx, code, bool(use_kernels)
+        )
+        out = out.at[ti].add(pooled)
     return out
 
 
@@ -928,7 +947,7 @@ def _fused_asym_lookup(
 
     _, b, _ = indices.shape
     e = packed.chunk_data.shape[-1]
-    interp = jax.default_backend() != "tpu"
+    interp = compat.pallas_interpret()
 
     # vectorized slot preprocessing: (S, B, s) pre-clipped local indices
     ti = packed.slot_table  # (S,)
@@ -1037,7 +1056,7 @@ def _ring_psum(x: jax.Array, axis: str) -> jax.Array:
     t with the add of step t-1 (latency-hiding scheduler), replacing the
     blocking fused all-reduce at the tail of the slot sweep.
     """
-    ksz = compat.axis_size(axis)
+    ksz = lax.axis_size(axis)
     if ksz == 1:
         return x
     perm = [(i, (i + 1) % ksz) for i in range(ksz)]
@@ -1102,7 +1121,7 @@ def partitioned_lookup(
             out = lax.psum(out, axis)
         # symmetric fallback: batch-split over the core axis.
         k = lax.axis_index(axis)
-        ksz = compat.axis_size(axis)
+        ksz = lax.axis_size(axis)
         b = idx.shape[1]
         bl = b // ksz
         idx_slice = lax.dynamic_slice_in_dim(idx, k * bl, bl, axis=1)
@@ -1129,8 +1148,9 @@ def partitioned_lookup(
         unique_cap=packed.unique_cap,
         cache_rows=packed.cache_rows,
         kernel_path=packed.kernel_path,
+        sym_static=packed.sym_static,
     )
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=(packed_specs, bspec),

@@ -299,7 +299,7 @@ def plan_symmetric(
     strategies: dict[int, Strategy] = {}
     for i in order:
         t = tables[i]
-        if t.bytes <= l1_left:
+        if t.bytes <= l1_left and model.fits_l1(t):
             strat, _ = model.best_strategy(
                 t, batch, n_cores, (Strategy.L1, Strategy.L1_UB),
                 freq_of(freqs, i),

@@ -556,6 +556,16 @@ def _payload_indices(q) -> np.ndarray:
     return np.asarray(q["indices"] if isinstance(q, Mapping) else q)
 
 
+def _place_on_mesh(packed, mesh):
+    """Packed arrays placed one core per device when the mesh's ``model``
+    axis matches the plan; a ``simulate=True`` build keeps them as built."""
+    if packed.n_cores != dict(mesh.shape).get("model", 1):
+        return packed
+    from repro.core.partition import place_packed
+
+    return place_packed(packed, mesh)
+
+
 class InferenceEngine:
     """The facade: plan → access-reduction arming → pack → (optional)
     autotune, built once by :meth:`build`, exposing ``lookup`` / ``serve``
@@ -726,7 +736,7 @@ class InferenceEngine:
             config=config,
             workload=workload,
             bag=bag,
-            packed=packed,
+            packed=_place_on_mesh(packed, mesh),
             mesh=mesh,
             freqs=freqs,
             table_data=table_data,
@@ -847,16 +857,17 @@ class InferenceEngine:
     def heal(self) -> dict:
         """Targeted repair of corrupt buffer regions: re-materialize them
         from the source tables (bit-exact) or zero-quarantine regions with
-        no source, replacing ``self.packed``.  The jitted steps bake the
-        packed arrays as constants — after a heal the caller must rebuild
-        its step (``serve``'s integrity wiring does this and swaps it in
+        no source, replacing ``self.packed``.  The repo's steps pass
+        ``engine.packed`` to their jitted forward on every call; a step that
+        closes over the packed arrays must be rebuilt after a heal
+        (``serve``'s integrity wiring does this and swaps it in
         atomically)."""
         if self.manifest is None:
             return {"healed": [], "quarantined": [], "clean": True}
         new_packed, report = self.manifest.repair(
             self.packed, self.plan, self.workload.tables, self._table_data
         )
-        self.packed = new_packed
+        self.packed = _place_on_mesh(new_packed, self.mesh)
         return report
 
     # -- execution ----------------------------------------------------------
@@ -897,27 +908,35 @@ class InferenceEngine:
         """Partitioned pooled lookup: per-table index arrays (or the stacked
         (N, B, s_max) tensor with ``-1`` padding) → (N, B, E).  Exactly
         ``bag.apply`` under the config's executor knobs — jit-able."""
+        return self._apply(self.packed, indices)
+
+    def _apply(self, packed, indices):
         self._require_executable()
         return self.bag.apply(
-            self.packed,
+            packed,
             indices,
             mesh=self.mesh,
             use_kernels=self._use_kernels,
             reduce_mode=self.config.reduce_mode,
         )
 
+    def jitted_lookup(self):
+        """:meth:`lookup` jitted with the packed tables as an argument:
+        ``fn(packed, indices) -> (N, B, E)``.  A jitted closure over
+        ``self.packed`` would bake every table into the executable."""
+        import jax
+
+        return jax.jit(self._apply)
+
     def _default_step(self):
         """payloads (list of queries) → (N, B, E) numpy, jitted once."""
         import jax
-        import jax.numpy as jnp
 
-        apply = jax.jit(self.lookup)
+        apply = self.jitted_lookup()
 
         def step(payloads):
-            idx = jnp.asarray(
-                np.stack([_payload_indices(q) for q in payloads], axis=1)
-            )
-            return np.asarray(jax.block_until_ready(apply(idx)))
+            idx = np.stack([_payload_indices(q) for q in payloads], axis=1)
+            return np.asarray(jax.block_until_ready(apply(self.packed, idx)))
 
         step.bag = self.bag
         return step

@@ -10,7 +10,9 @@ each grid step DMAs exactly the one indexed row HBM→VMEM, and the Pallas
 pipeline double-buffers the row fetches automatically (the row for step
 ``(b, j+1)`` is in flight while step ``(b, j)`` accumulates).  The output
 block for query ``b`` stays resident in VMEM across the ``s`` accumulation
-steps (consecutive grid steps map to the same output block).
+steps (consecutive grid steps map to the same output block).  Table and
+output carry a unit middle axis so that a one-row block spans the full last
+two dims, as the TPU's (8, 128) block rule requires.
 """
 from __future__ import annotations
 
@@ -24,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro import compat
 
 
-def _gm_kernel(idx_ref, row_ref, out_ref, *, seq: int):
+def _gm_kernel(idx_ref, row_ref, out_ref):
     """Accumulate one streamed row into the per-query output block."""
     del idx_ref  # consumed by the index_map
     j = pl.program_id(1)
@@ -37,7 +39,6 @@ def _gm_kernel(idx_ref, row_ref, out_ref, *, seq: int):
     @pl.when(j > 0)
     def _acc():
         out_ref[...] += row
-    del seq
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -52,24 +53,24 @@ def embedding_bag_gm(
     b, s = indices.shape
     flat_idx = indices.reshape(-1).astype(jnp.int32)
 
-    grid = (b, s)
-    kernel = functools.partial(_gm_kernel, seq=s)
     out = pl.pallas_call(
-        kernel,
+        _gm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(b, s),
             in_specs=[
-                # one (1, E) row per grid step; the row number comes from the
+                # one row per grid step; the row number comes from the
                 # prefetched indices -> pipelined, double-buffered row DMA.
-                pl.BlockSpec((1, e), lambda bi, j, idx: (idx[bi * s + j], 0)),
+                pl.BlockSpec(
+                    (1, 1, e), lambda bi, j, idx: (idx[bi * s + j], 0, 0)
+                ),
             ],
-            out_specs=pl.BlockSpec((1, e), lambda bi, j, idx: (bi, 0)),
+            out_specs=pl.BlockSpec((1, 1, e), lambda bi, j, idx: (bi, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, e), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, e), jnp.float32),
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(flat_idx, table)
-    return out
+    )(flat_idx, table.reshape(m, 1, e))
+    return out.reshape(b, e)

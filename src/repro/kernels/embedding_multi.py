@@ -25,6 +25,12 @@ pass** over the ragged packed buffer (core.partition ``layout="ragged"``):
   independent of index values), GM/L1-coded steps pool row-at-a-time — one
   lookup position per accumulation pass — reproducing the paper's
   per-strategy data flow without any per-slot ``lax.switch``;
+* **batch on lanes**: inside the kernel indices are ``(s, Bt)`` and the
+  pooled output is ``(E, Bt)`` per slot, so a lookup position is one row
+  read (never a dynamic column slice, which Mosaic cannot lower), one-hots
+  are ``(block_r, Bt)`` and the output tile is lane-dense; the wrapper
+  transposes in and out.  Every GEMM runs at HIGHEST precision, which keeps
+  one-hot row copies exact on the MXU;
 * out-of-window / invalid (``-1``) indices contribute exact zeros (no
   redirect row); consecutive steps of one slot accumulate into the same
   output block (``step_base == 0`` marks the first block and init-writes);
@@ -36,7 +42,7 @@ Access-reduction subsystem (DESIGN.md §6, both knobs off by default):
   batch-prep time (sort + first-occurrence ranks, padded to the static
   ``unique_cap``); each step gathers every unique row in its window exactly
   once (one-hot ``(U, block_r) @ window`` GEMM) and scatters back to batch
-  rows with the per-slot multiplicity matrix (``(B, U) @ rows`` GEMM) —
+  rows with the per-slot multiplicity matrix (``rows_uᵀ @ (U, B)`` GEMM) —
   per-lookup HBM row reads become per-unique-row reads.  Slots whose
   distinct-row count overflows ``unique_cap`` spill the overflow lookups to
   the cold row-at-a-time path in the same step (exact, just slower);
@@ -54,10 +60,12 @@ gather has two implementations sharing the uniq/cnt machinery —
 * **onehot** (``kpath == 0``): materialize the ``(U, block_r)`` equality
   one-hot and gather via a GEMM on the MXU (dense in ``U·block_r``);
 * **sparse** (``kpath == 1``): CSR-style true-sparse gather — ``uniq`` is
-  already sorted ascending, so a ``fori_loop`` of masked
-  ``dynamic_slice_in_dim`` row copies pulls each in-window unique row out of
-  the streamed ``(block_r, E)`` window directly; the shared multiplicity
-  GEMM (``cnt @ rows_u``) is the segment-sum scatter back to batch rows.
+  already sorted ascending, so the unique rows inside a step's window are
+  one contiguous run ``[lo, hi)`` (found by a binary search at batch-prep
+  time); a loop over exactly that run copies each row out of the streamed
+  ``(block_r, E)`` window into a ``(U, E)`` scratch, and the shared
+  multiplicity GEMM (``rows_uᵀ @ cnt``) is the segment-sum scatter back to
+  batch rows.  The run's scratch rows are zeroed again after the GEMM.
 
 Both produce the same ``rows_u`` **bitwise** (a one-hot matvec against
 finite data is an exact row copy: ``0·x + 1·row = row``), so the paths are
@@ -81,15 +89,44 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import compat
+from repro.kernels.embedding_ub import tdot
 
-# VMEM budget (bytes) for the resident batch tile + streamed window; beyond
-# it the batch is chunked outside the pallas_call (each chunk re-streams the
-# buffer — unavoidable once the batch no longer fits on-chip).
-_VMEM_BUDGET = 8 * 1024 * 1024
+# padded-VMEM budget (bytes) for the resident batch tile, the streamed
+# window and the kernel's temporaries; beyond it the batch is chunked
+# outside the pallas_call (each chunk re-streams the buffer — unavoidable
+# once the batch no longer fits on-chip).
+_VMEM_BUDGET = 24 * 1024 * 1024
 
 
 def _align8(n: int) -> int:
     return int(-(-n // 8) * 8)
+
+
+def ragged_vmem_bytes(
+    bb: int,
+    seq: int,
+    e: int,
+    block_r: int,
+    *,
+    unique_cap: int = 0,
+    cache_rows: int = 0,
+) -> int:
+    """Padded VMEM working set of one fused-kernel grid step with ``bb``
+    resident batch rows (:func:`repro.compat.vmem_bytes` per buffer):
+    double-buffered blocks plus the one-hot, count and partial temporaries.
+    ``unique_cap`` adds the dedup blocks and the sparse-gather row scratch,
+    ``cache_rows`` the hot-position tile, the pinned cache and its counts."""
+    v = compat.vmem_bytes
+    blocks = v((seq, bb)) + v((e, bb)) + v((block_r, e))  # idx, out, window
+    work = 3 * v((block_r, bb)) + 3 * v((e, bb))  # one-hots, partials
+    if unique_cap:
+        blocks += v((unique_cap, 1)) + v((unique_cap, bb))  # uniq, cnt
+        work += 2 * v((unique_cap, block_r))  # one-hot gather
+        work += 3 * v((unique_cap, e))  # gathered rows (+ scratch)
+    if cache_rows:
+        blocks += v((seq, bb)) + v((cache_rows, e))  # hidx, cache
+        work += 2 * v((cache_rows, bb))
+    return 2 * blocks + work
 
 
 def ragged_block_b(
@@ -108,23 +145,19 @@ def ragged_block_b(
     Returns ``(block_b, n_chunks)``: the kernel keeps ``block_b`` batch rows
     resident in VMEM; ``n_chunks == 1`` means the whole (padded) batch is
     folded into the one-hot matmul and every buffer window streams once per
-    core.  ``unique_cap``/``cache_rows`` charge the dedup multiplicity tile
-    (``block_b × U``), the hot-position tile, and the pinned ``(C, E)``
-    residency cache against the same budget.  Shared by the executor and the
-    modeled-traffic accounting.
+    core.  The auto pick is the whole batch when its padded working set
+    (:func:`ragged_vmem_bytes`) fits ``vmem_budget``, else the largest
+    multiple of 128 rows (one lane tile) that does.  Shared by the executor
+    and the modeled-traffic accounting.
     """
+    kw = dict(unique_cap=unique_cap, cache_rows=cache_rows)
     if block_b is None:
-        # per batch row: idx (s) + out (e) + count/eq row (block_r) + partial
-        # (e), f32; plus dedup cnt (U) + hot-position (s) + hot-count (C)
-        # rows when armed; plus the double-buffered (block_r, E) window and
-        # the resident cache itself.
-        per_row = 4 * (
-            seq * (2 if cache_rows else 1)
-            + 2 * e + block_r + unique_cap + cache_rows
-        )
-        fixed = 2 * block_r * e * 4 + cache_rows * e * 4 + unique_cap * 4
-        fit = (vmem_budget - fixed) // max(per_row, 1)
-        block_b = max(8, (int(fit) // 8) * 8)
+        block_b = _align8(b)
+        if ragged_vmem_bytes(block_b, seq, e, block_r, **kw) > vmem_budget:
+            # the working set is affine in bb over whole 128-lane tiles
+            fixed = ragged_vmem_bytes(0, seq, e, block_r, **kw)
+            per_tile = ragged_vmem_bytes(128, seq, e, block_r, **kw) - fixed
+            block_b = 128 * max(1, (vmem_budget - fixed) // per_tile)
     block_b = min(block_b, _align8(b))
     block_b = max(8, (block_b // 8) * 8)
     n_chunks = -(-b // block_b)
@@ -141,7 +174,7 @@ def _ragged_kernel(
     block_r: int, seq: int, unique_cap: int, cache_rows: int,
     use_kpath: bool = False,
 ):
-    del slot_ref, blk_ref  # consumed by the index_maps
+    del blk_ref  # consumed by the index_maps
     t = pl.program_id(0)
     base = base_ref[t]
     strat = strat_ref[t]
@@ -150,89 +183,107 @@ def _ragged_kernel(
         # per-step work flags (bit 0: slot has spill, bit 1: slot has
         # cache hits) — lets the kernel skip guaranteed-zero loops.
         flags = refs.pop(0)[t]
-    kpath = refs.pop(0)[t] if use_kpath else None
-    idx_ref = refs.pop(0)  # full lidx, or the overflow spill when dedup'd
-    uniq_ref = refs.pop(0) if unique_cap else None
-    cnt_ref = refs.pop(0) if unique_cap else None
-    hidx_ref = refs.pop(0) if cache_rows else None
-    cache_ref = refs.pop(0) if cache_rows else None
-    window_ref, out_ref = refs
-    # (Bt, s) chunk-local indices; -1 / out-of-window never match the iota.
-    rel = idx_ref[0] - base
-    bt = rel.shape[0]
+    if use_kpath:
+        # gather-path selector, the step's in-window run [lo, hi) of the
+        # slot's sorted unique ids, and those ids flattened (S+1)*U
+        kpath = refs.pop(0)[t]
+        lo = refs.pop(0)[t]
+        hi = refs.pop(0)[t]
+        uniq_flat_ref = refs.pop(0)
+    idx_ref = refs.pop(0)  # (1, s, Bt): full lidx, or the dedup spill
+    uniq_ref = refs.pop(0) if unique_cap else None  # (1, U, 1)
+    cnt_ref = refs.pop(0) if unique_cap else None  # (1, U, Bt)
+    hidx_ref = refs.pop(0) if cache_rows else None  # (1, s, Bt)
+    cache_ref = refs.pop(0) if cache_rows else None  # (C, E)
+    window_ref, out_ref = refs[:2]
+    rows_ref = refs[2] if use_kpath else None  # (U, E) scratch
+    bt = idx_ref.shape[-1]
+    e = window_ref.shape[1]
     window = window_ref[...].astype(jnp.float32)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, block_r), 1)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (block_r, 1), 0)
+
+    def onehot(j):
+        # (block_r, Bt): lookup position j of every batch row against the
+        # window's rows; -1 / out-of-window indices match nothing.
+        return (iota == idx_ref[0, pl.ds(j, 1), :] - base).astype(jnp.float32)
 
     def _ub_onehot():
         # UB: fold every lookup position into ONE count matrix, then a single
         # conflict-free GEMM on the MXU — run time independent of the index
         # values (the paper's vectorized UB look-up).
-        def cnt(j, c):
-            return c + (rel[:, j][:, None] == iota).astype(jnp.float32)
-
         counts = jax.lax.fori_loop(
-            0, seq, cnt, jnp.zeros((bt, block_r), jnp.float32)
+            0, seq, lambda j, c: c + onehot(j),
+            jnp.zeros((block_r, bt), jnp.float32),
         )
-        return jnp.dot(counts, window, preferred_element_type=jnp.float32)
+        return tdot(window, counts)
 
     def _gm_rowstream():
         # GM/L1: row-at-a-time pooling — one lookup position per pass through
         # the accumulation buffer (the paper's "read one row at a time ...
         # followed by pooling this row in an accumulation buffer").
-        def pos(j, acc):
-            eq = (rel[:, j][:, None] == iota).astype(jnp.float32)
-            return acc + jnp.dot(eq, window, preferred_element_type=jnp.float32)
-
         return jax.lax.fori_loop(
-            0, seq, pos, jnp.zeros((bt, window.shape[1]), jnp.float32)
+            0, seq, lambda j, acc: acc + tdot(window, onehot(j)),
+            jnp.zeros((e, bt), jnp.float32),
         )
 
     if unique_cap:
         # dedup'd path (all strategies): gather each unique row in this
-        # window exactly ONCE (one-hot (U, block_r) GEMM), then scatter the
-        # pooled rows back to batch positions with the multiplicity matrix —
-        # per-unique-row reads instead of per-lookup reads, conflict-free by
-        # construction.  idx_ref carries only the unique_cap overflow spill,
-        # row-streamed cold alongside — but only on slots whose flag says
-        # something actually spilled (the common case skips the dead loop).
-        rel_u = uniq_ref[0] - base  # (U,); -1 pads never match
+        # window exactly ONCE, then scatter the pooled rows back to batch
+        # positions with the multiplicity matrix — per-unique-row reads
+        # instead of per-lookup reads, conflict-free by construction.
+        # idx_ref carries only the unique_cap overflow spill, row-streamed
+        # cold alongside — but only on slots whose flag says something
+        # actually spilled (the common case skips the dead loop).
+        def scatter(rows_u):
+            # (U, E) unique rows -> (E, Bt) pooled partial; one GEMM shared
+            # by both gather paths, so they agree bit for bit.
+            return tdot(rows_u, cnt_ref[0])
 
-        def _rows_onehot():
-            # dense gather: (U, block_r) equality one-hot @ window on the MXU
-            equ = (rel_u[:, None] == iota).astype(jnp.float32)
-            return jnp.dot(equ, window, preferred_element_type=jnp.float32)
+        def _partial_onehot():
+            # dense gather: (U, block_r) equality one-hot on the MXU
+            iota_r = jax.lax.broadcasted_iota(jnp.int32, (1, block_r), 1)
+            equ = (uniq_ref[0] - base == iota_r).astype(jnp.float32)
+            return scatter(jnp.dot(
+                equ, window,
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            ))
 
-        def _rows_sparse():
-            # true-sparse gather: uniq is sorted, so each in-window unique
-            # row is a single masked dynamic_slice row copy — no U·block_r
-            # one-hot materialization.  Bit-identical to _rows_onehot: a
-            # one-hot matvec against finite data IS an exact row copy.
-            def gather(u, acc):
-                r = rel_u[u]
-                inb = (r >= 0) & (r < block_r)
-                row = jax.lax.dynamic_slice_in_dim(
-                    window, jnp.clip(r, 0, block_r - 1), 1, axis=0
+        def _partial_sparse():
+            # true-sparse gather: copy just the window's unique rows
+            # [lo, hi) — no (block_r, U) one-hot.  Bit-identical to the
+            # one-hot path: a one-hot matvec against finite data IS an exact
+            # row copy, and rows outside the run stay zero.
+            first = slot_ref[t] * unique_cap
+
+            def copy(u, c):
+                r = uniq_flat_ref[first + u] - base
+                rows_ref[pl.ds(u, 1), :] = window_ref[pl.ds(r, 1), :].astype(
+                    jnp.float32
                 )
-                row = jnp.where(inb, row, jnp.zeros_like(row))
-                return jax.lax.dynamic_update_slice_in_dim(acc, row, u, axis=0)
+                return c
 
-            return jax.lax.fori_loop(
-                0, unique_cap, gather,
-                jnp.zeros((unique_cap, window.shape[1]), jnp.float32),
-            )
+            def clear(u, c):
+                rows_ref[pl.ds(u, 1), :] = jnp.zeros((1, e), jnp.float32)
+                return c
+
+            jax.lax.fori_loop(lo, hi, copy, 0)
+            out = scatter(rows_ref[...])
+            jax.lax.fori_loop(lo, hi, clear, 0)
+            return out
 
         if use_kpath:
-            rows_u = jax.lax.cond(kpath == 1, _rows_sparse, _rows_onehot)
+            @pl.when(t == 0)
+            def _zero_rows():
+                rows_ref[...] = jnp.zeros(rows_ref.shape, jnp.float32)
+
+            partial = jax.lax.cond(kpath == 1, _partial_sparse, _partial_onehot)
         else:
-            rows_u = _rows_onehot()
-        # segment-sum scatter back to batch rows (shared by both paths)
-        partial = jnp.dot(
-            cnt_ref[0], rows_u, preferred_element_type=jnp.float32
-        )
+            partial = _partial_onehot()
         partial += jax.lax.cond(
             (flags & 1) > 0,
             _gm_rowstream,
-            lambda: jnp.zeros((bt, window.shape[1]), jnp.float32),
+            lambda: jnp.zeros((e, bt), jnp.float32),
         )
     else:
         # UB strategies (GM-UB=1, L1-UB=3) use the vectorized one-hot path.
@@ -247,29 +298,23 @@ def _ragged_kernel(
             # UB-style one-hot GEMM, folded in once on the slot's first
             # step — skipped outright on slots with no cached rows.
             def _hot_fold():
-                hrel = hidx_ref[0]  # (Bt, s) cache positions, -1 = miss
                 iota_c = jax.lax.broadcasted_iota(
-                    jnp.int32, (1, cache_rows), 1
+                    jnp.int32, (cache_rows, 1), 0
                 )
 
                 def hcnt(j, c):
-                    return c + (
-                        hrel[:, j][:, None] == iota_c
-                    ).astype(jnp.float32)
+                    hit = iota_c == hidx_ref[0, pl.ds(j, 1), :]
+                    return c + hit.astype(jnp.float32)
 
                 counts_h = jax.lax.fori_loop(
-                    0, seq, hcnt, jnp.zeros((bt, cache_rows), jnp.float32)
+                    0, seq, hcnt, jnp.zeros((cache_rows, bt), jnp.float32)
                 )
-                return jnp.dot(
-                    counts_h,
-                    cache_ref[...].astype(jnp.float32),
-                    preferred_element_type=jnp.float32,
-                )
+                return tdot(cache_ref[...].astype(jnp.float32), counts_h)
 
             out = out + jax.lax.cond(
                 (flags & 2) > 0,
                 _hot_fold,
-                lambda: jnp.zeros((bt, window.shape[1]), jnp.float32),
+                lambda: jnp.zeros((e, bt), jnp.float32),
             )
         out_ref[0] = out
 
@@ -397,9 +442,11 @@ def multi_embedding_bag_ragged(
         _ragged_kernel, block_r=block_r, seq=seq,
         unique_cap=unique_cap, cache_rows=cache_rows, use_kpath=use_kpath,
     )
+    step_slot = step_slot.astype(jnp.int32)
+    step_base = step_base.astype(jnp.int32)
     prefetch = [
-        step_slot.astype(jnp.int32),
-        step_base.astype(jnp.int32),
+        step_slot,
+        step_base,
         step_block.astype(jnp.int32),
         step_strategy.astype(jnp.int32),
     ]
@@ -417,11 +464,25 @@ def multi_embedding_bag_ragged(
         slot_flags = spill_any.astype(jnp.int32) + 2 * hot_any.astype(
             jnp.int32
         )
-        prefetch.append(jnp.take(slot_flags, step_slot.astype(jnp.int32)))
+        prefetch.append(jnp.take(slot_flags, step_slot))
     if use_kpath:
-        # per-step gather-path selector, appended LAST so the positional
-        # index_map prefix (t, ss, sb, sk, ...) stays stable.
-        prefetch.append(step_kpath.astype(jnp.int32))
+        # per-step gather-path selector plus, for the sparse gather, each
+        # step's run [lo, hi) of in-window ids in its slot's sorted unique
+        # list (pads, -1, sort last) and the unique ids themselves.
+        key = jnp.where(uniq < 0, jnp.iinfo(jnp.int32).max, uniq)
+
+        def run_bounds(first_row):
+            per_slot = jax.vmap(
+                lambda k: jnp.searchsorted(k, first_row).astype(jnp.int32)
+            )(key)  # (S+1, n_steps)
+            return per_slot[step_slot, jnp.arange(n_steps)]
+
+        prefetch += [
+            step_kpath.astype(jnp.int32),
+            run_bounds(step_base),
+            run_bounds(step_base + block_r),
+            uniq.reshape(-1),
+        ]
 
     # the step's slot-indexed batch tiles are resident across the slot's
     # (consecutive) steps — refetched only on slot change; the (block_r, E)
@@ -430,30 +491,31 @@ def multi_embedding_bag_ragged(
     # index_map pins it VMEM-resident for the whole grid.  The index_maps
     # take (t, *prefetch_refs) — variadic since the flags prefetch is only
     # present when the access-reduction subsystem is armed.
-    in_specs = [
-        pl.BlockSpec((1, bb, seq), lambda t, ss, *_: (ss[t], 0, 0)),
-    ]
+    slot_block = lambda t, ss, *_: (ss[t], 0, 0)  # noqa: E731
+    in_specs = [pl.BlockSpec((1, seq, bb), slot_block)]
     if unique_cap:
         in_specs += [
-            pl.BlockSpec((1, unique_cap), lambda t, ss, *_: (ss[t], 0)),
-            pl.BlockSpec(
-                (1, bb, unique_cap), lambda t, ss, *_: (ss[t], 0, 0)
-            ),
+            pl.BlockSpec((1, unique_cap, 1), slot_block),
+            pl.BlockSpec((1, unique_cap, bb), slot_block),
         ]
     if cache_rows:
         in_specs += [
-            pl.BlockSpec((1, bb, seq), lambda t, ss, *_: (ss[t], 0, 0)),
+            pl.BlockSpec((1, seq, bb), slot_block),
             pl.BlockSpec((cache_rows, e), lambda t, ss, *_: (0, 0)),
         ]
     in_specs.append(
         pl.BlockSpec((block_r, e), lambda t, ss, sb, sk, *_: (sk[t], 0))
     )
+    scratch = [pltpu.VMEM((unique_cap, e), jnp.float32)] if use_kpath else []
+    vmem = ragged_vmem_bytes(
+        bb, seq, e, block_r, unique_cap=unique_cap, cache_rows=cache_rows
+    )
 
     def one_pass(tiles: dict) -> jax.Array:
-        """Per-batch-chunk resident tiles -> (S+1, bb, E) pooled."""
+        """Per-batch-chunk resident tiles -> (S+1, E, bb) pooled."""
         inputs = [tiles["lidx"]]
         if unique_cap:
-            inputs += [uniq, tiles["cnt"]]
+            inputs += [uniq[:, :, None], tiles["cnt"]]
         if cache_rows:
             inputs += [tiles["hidx"], cache]
         inputs.append(buffer)
@@ -463,39 +525,37 @@ def multi_embedding_bag_ragged(
                 num_scalar_prefetch=len(prefetch),
                 grid=(n_steps,),
                 in_specs=in_specs,
-                out_specs=pl.BlockSpec(
-                    (1, bb, e), lambda t, ss, *_: (ss[t], 0, 0)
-                ),
+                out_specs=pl.BlockSpec((1, e, bb), slot_block),
+                scratch_shapes=scratch,
             ),
-            out_shape=jax.ShapeDtypeStruct((s_slots + 1, bb, e), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((s_slots + 1, e, bb), jnp.float32),
             compiler_params=compat.tpu_compiler_params(
-                dimension_semantics=("arbitrary",),
+                dimension_semantics=("arbitrary",), vmem_bytes=vmem,
             ),
             interpret=interpret,
         )(*prefetch, *inputs)
 
-    tiles = {"lidx": lidx}
+    # batch on lanes: (S+1, B, ...) -> (S+1, ..., B)
+    tiles = {"lidx": lidx.swapaxes(1, 2)}
     if unique_cap:
-        tiles["cnt"] = cnt
+        tiles["cnt"] = cnt.swapaxes(1, 2)
     if cache_rows:
-        tiles["hidx"] = hidx
+        tiles["hidx"] = hidx.swapaxes(1, 2)
     if n_chunks == 1:
         out = one_pass(tiles)
     else:
         # batch exceeds the VMEM budget: chunk it OUTSIDE the pallas_call;
         # each chunk is one full streaming pass over the buffer (the unique
         # table and the resident cache are chunk-invariant and ride along).
-        def split(x):  # (S+1, n_chunks*bb, ...) -> (n_chunks, S+1, bb, ...)
-            shp = x.shape
-            return x.reshape(
-                shp[0], n_chunks, bb, *shp[2:]
-            ).swapaxes(0, 1)
+        def split(x):  # (S+1, d, n_chunks*bb) -> (n_chunks, S+1, d, bb)
+            s1, d, _ = x.shape
+            return x.reshape(s1, d, n_chunks, bb).transpose(2, 0, 1, 3)
 
         out = jax.lax.map(
             one_pass, {k: split(v) for k, v in tiles.items()}
-        )  # (n_chunks, S+1, bb, E)
-        out = out.swapaxes(0, 1).reshape(s_slots + 1, n_chunks * bb, e)
-    return out[:s_slots, :b]
+        )  # (n_chunks, S+1, E, bb)
+        out = out.transpose(1, 2, 0, 3).reshape(s_slots + 1, e, n_chunks * bb)
+    return out[:s_slots, :, :b].swapaxes(1, 2)
 
 
 # --------------------------------------------------------------------------
@@ -510,15 +570,13 @@ def _dense_kernel(idx_ref, chunk_ref, out_ref, *, block_b: int, seq: int, batch:
     def query(r, _):
         def lookup(j, acc):
             idx = idx_ref[(si * batch + bi * block_b + r) * seq + j]
-            row = chunk_ref[0]  # (R+1, E)
-            return acc + jax.lax.dynamic_slice_in_dim(row, idx, 1, axis=0).astype(
-                jnp.float32
-            )
+            row = chunk_ref[0, pl.ds(idx, 1), :]  # (1, E) of the (R+1, E) chunk
+            return acc + row.astype(jnp.float32)
 
         acc = jax.lax.fori_loop(
             0, seq, lookup, jnp.zeros((1, chunk_ref.shape[-1]), jnp.float32)
         )
-        out_ref[0, r, :] = acc[0]
+        out_ref[0, pl.ds(r, 1), :] = acc
         return _
 
     jax.lax.fori_loop(0, block_b, query, None)

@@ -1,9 +1,9 @@
 """Jit'd strategy dispatch for the embedding-lookup kernels.
 
 ``embedding_bag(table, indices, strategy)`` is the single entry point used by
-the core library; the planner decides the strategy per table/chunk.  On
-non-TPU backends the Pallas kernels run in interpret mode (slow, correct) —
-tests exercise that path; real deployments lower the same code to TPU.
+the core library; the planner decides the strategy per table/chunk.  The
+Pallas kernels compile on a TPU and run in interpret mode on the CPU test
+backend (:func:`repro.compat.pallas_interpret`).
 """
 from __future__ import annotations
 
@@ -12,15 +12,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import compat
 from repro.core.strategies import Strategy
 from repro.kernels import ref
 from repro.kernels.embedding_gm import embedding_bag_gm
 from repro.kernels.embedding_l1 import embedding_bag_l1
 from repro.kernels.embedding_ub import embedding_bag_ub
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
@@ -91,7 +88,7 @@ def embedding_bag(
         return ref.embedding_bag_ref(table, indices, pooling=pooling)
     strategy = Strategy(strategy)
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = compat.pallas_interpret()
 
     # custom VJP: forward runs the Pallas strategy kernel, backward is the
     # standard scatter-add of pooled cotangents (trainable lookup layers).

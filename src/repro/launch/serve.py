@@ -23,7 +23,8 @@ the admission queue and sheds stale requests; ``--set degrade_after=3``
 arms the degraded-mode fallback (XLA reference path) against a crashing
 fused kernel.  The per-run report includes the request-accounting
 counters (submitted/served/shed/rejected/deadline_misses/batch_failures/
-degraded_batches).
+degraded_batches).  The run exits non-zero when any batch failed or was
+served degraded, or when queries were left unserved.
 
 Legacy flag spellings (``--planner``, ``--layout``, ``--kernels``,
 ``--reduce``, ``--autotune``, ``--dedup``, ``--cache``, ``--replan``,
@@ -35,6 +36,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import warnings
 from pathlib import Path
 
@@ -226,6 +228,61 @@ def config_from_args(args) -> EngineConfig:
     return config
 
 
+def dlrm_step_maker(cfg, params):
+    """``make_step(engine)`` for the serving loop: one step over request
+    payloads is the full DLRM forward on the engine's packed embeddings.
+    The drift policy re-invokes it on every shadow re-pack.  The packed
+    tables and the tower weights are arguments of the jitted forward, not
+    constants baked into it, so the executable stays small enough for the
+    compile cache.  ``step.lower(batch)`` lowers that forward for a
+    ``{"dense", "indices"}`` batch."""
+    import jax
+
+    from repro.models.dlrm import forward_packed
+
+    mlp = {k: params[k] for k in ("bottom", "top")}
+
+    def make_step(engine):
+        @jax.jit
+        def infer(packed, mlp, batch):
+            return forward_packed(
+                cfg, engine.bag, packed, mlp, batch,
+                mesh=engine.mesh, use_kernels=engine._use_kernels,
+                reduce_mode=engine.config.reduce_mode,
+            )
+
+        def step(payloads):
+            dense = np.stack([q["dense"] for q in payloads])
+            idx = np.stack([q["indices"] for q in payloads], axis=1)
+            batch = {"dense": dense, "indices": idx}
+            return np.asarray(
+                jax.block_until_ready(infer(engine.packed, mlp, batch))
+            )
+
+        step.lower = lambda batch: infer.lower(engine.packed, mlp, batch)
+        return step
+
+    return make_step
+
+
+def serving_faults(stats: dict, unserved: int = 0) -> list[str]:
+    """What went wrong in a serving run, from the server's counters: empty
+    when every submitted query was served by the primary path."""
+    faults = [
+        f"{k}={stats[k]}" for k in ("batch_failures", "degraded_batches")
+        if stats.get(k)
+    ]
+    if unserved:
+        faults.append(f"unserved={unserved}")
+    return faults
+
+
+def _exit_on_faults(faults: list[str]) -> None:
+    if faults:
+        print(f"[serve] FAILED: {' '.join(faults)}")
+        sys.exit(1)
+
+
 def main(argv=None):
     args = build_parser().parse_args(argv)
     config = config_from_args(args)  # also resolves --preset into args
@@ -245,7 +302,10 @@ def main(argv=None):
     from repro.data import distributions as dist_lib
     from repro.data.workloads import get_workload, small_workload
     from repro.engine import InferenceEngine
-    from repro.models.dlrm import DLRMConfig, forward_packed, init_dlrm
+    from repro.models.dlrm import DLRMConfig, init_dlrm
+    from repro.serving.server import BatchExecutionError
+
+    compat.enable_compilation_cache()
 
     wl = (small_workload(batch=batch) if args.workload == "smoke"
           else get_workload(args.workload, batch))
@@ -277,28 +337,7 @@ def main(argv=None):
     )
     dist0 = schedule.at(0) if schedule else resolved
 
-    def make_step(engine):
-        """One serving step over request payloads: the full DLRM forward on
-        the engine's packed embeddings.  Re-invoked by the drift policy on
-        every shadow re-pack."""
-
-        @jax.jit
-        def infer(batch):
-            return forward_packed(
-                cfg, engine.bag, engine.packed, params, batch,
-                mesh=engine.mesh, use_kernels=engine._use_kernels,
-                reduce_mode=engine.config.reduce_mode,
-            )
-
-        def step(payloads):
-            dense = jax.numpy.stack([q["dense"] for q in payloads])
-            idx = jax.numpy.stack([q["indices"] for q in payloads], axis=1)
-            return np.asarray(
-                jax.block_until_ready(infer({"dense": dense, "indices": idx}))
-            )
-
-        return step
-
+    make_step = dlrm_step_maker(cfg, params)
     engine = InferenceEngine.build(
         params["tables"], wl, config, mesh=mesh, freqs=freqs0
     )
@@ -316,8 +355,10 @@ def main(argv=None):
 
     rng = np.random.default_rng(0)
     step0 = make_step(engine)  # one compile serves every traffic label
+    faults = []
     for label, dist in _resolve_dists(args.distribution):
         srv = engine.serve(make_step=lambda eng: step0, split_fn=split)
+        failed = 0
         for _ in range(n_batches):
             b = dist_lib.sample_workload(rng, wl, dist, batch)
             dense = rng.standard_normal(
@@ -327,7 +368,11 @@ def main(argv=None):
                 for q in range(batch)
             ]
             srv.pump()
-            assert handles[0].done()
+            for h in handles:
+                try:
+                    h.result()
+                except BatchExecutionError:
+                    failed += 1
         unserved = srv.drain()
         if unserved:
             print(f"[serve] WARNING: {len(unserved)} queries left unserved")
@@ -335,6 +380,10 @@ def main(argv=None):
         print(f"[serve] dist={label:8s} p50={_fmt_us(s['p50_us'])} "
               f"p99={_fmt_us(s['p99_us'])} tps={s['tps']:9.0f}")
         _print_robustness(s)
+        faults += [f"{label}:{f}" for f in serving_faults(s, len(unserved))]
+        if failed:
+            faults.append(f"{label}:failed_requests={failed}")
+    _exit_on_faults(faults)
 
 
 def _fmt_us(v) -> str:
@@ -396,6 +445,7 @@ def _serve_drift(args, wl, schedule, engine, make_step, split, *, n_dense):
     for ev in s.get("replan", {}).get("events", []):
         print(f"[serve]   replan@batch={ev['batch']} drift={ev['drift']:.3f} "
               f"parity_ok={ev['parity_ok']}")
+    _exit_on_faults(serving_faults(s, len(unserved)))
 
 
 if __name__ == "__main__":

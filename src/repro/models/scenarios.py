@@ -181,12 +181,12 @@ class _TowerScenario:
         import jax
         import jax.numpy as jnp
 
-        lookup = jax.jit(engine.lookup)
+        lookup = engine.jitted_lookup()
         tower = self._tower_jit
 
         def step(payloads):
             batch = self.collate(payloads)
-            pooled = lookup(jnp.asarray(batch["indices"]))
+            pooled = lookup(engine.packed, jnp.asarray(batch["indices"]))
             return np.asarray(jax.block_until_ready(tower(pooled)))
 
         step.bag = engine.bag
@@ -292,12 +292,12 @@ class DLRMScenario(_TowerScenario):
         import jax
         import jax.numpy as jnp
 
-        lookup = jax.jit(engine.lookup)
+        lookup = engine.jitted_lookup()
         tower = self._tower_jit
 
         def step(payloads):
             batch = self.collate(payloads)
-            pooled = lookup(jnp.asarray(batch["indices"]))
+            pooled = lookup(engine.packed, jnp.asarray(batch["indices"]))
             return np.asarray(
                 jax.block_until_ready(
                     tower(pooled, jnp.asarray(batch["dense"]))

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig, ShapeCfg
 from repro.core.partition import vocab_parallel_embed
 from repro.models import layers as L
@@ -180,7 +179,7 @@ def init_params(cfg: ArchConfig, rng: jax.Array) -> Params:
 def embed_tokens(cfg: ArchConfig, params: Params, tokens: jax.Array, ctx: ShardCtx | None):
     if ctx is None:
         return jnp.take(params["embed"], tokens, axis=0)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda tab, tok: vocab_parallel_embed(tab, tok, ctx.model_axis),
         mesh=ctx.mesh,
         in_specs=(P(ctx.model_axis, None), P(ctx.batch_spec, None)),

@@ -126,3 +126,19 @@ def test_kernel_custom_vjp(strategy):
         ops.embedding_bag(t, idx, strategy, interpret=True) * w))(table)
     gr = jax.grad(lambda t: jnp.sum(ref.embedding_bag_ref(t, idx) * w))(table)
     np.testing.assert_allclose(np.asarray(gk), np.asarray(gr), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("strategy", [Strategy.L1, Strategy.L1_UB])
+def test_pinned_table_over_vmem_cap_is_refused(strategy):
+    """A table whose lane-padded copy exceeds the L1 kernels' VMEM cap is
+    refused at trace time, before the TPU compiler would be."""
+    from repro.kernels.embedding_l1 import PIN_VMEM_BYTES
+
+    rows = PIN_VMEM_BYTES // (128 * 4) + 8  # 16-wide f32 rows pad to 128 lanes
+    table = jax.ShapeDtypeStruct((rows, 16), jnp.float32)
+    idx = jax.ShapeDtypeStruct((8, 1), jnp.int32)
+    with pytest.raises(ValueError, match="pinned"):
+        jax.eval_shape(
+            lambda t, i: ops.embedding_bag(t, i, strategy, interpret=True),
+            table, idx,
+        )

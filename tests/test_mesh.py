@@ -26,7 +26,9 @@ from repro.core.mesh import (
 from repro.core.planner import plan_asymmetric
 from repro.core.traffic import modeled_cross_host_traffic
 from repro.data.distributions import Zipf, workload_probs
-from test_fused_executor import _emulate_sparse_rejoin, _local_partials
+from test_fused_executor import (
+    _emulate_sparse_rejoin, _full_lookup, _local_partials,
+)
 
 E = 16
 
@@ -60,10 +62,12 @@ def _hier_bag(wl, hosts, cph, model=None, **kw):
 
 
 def _emulated_lookup(bag, packed, sidx):
-    """Asymmetric partials + emulated sparse rejoin (hierarchical plans
-    never have a symmetric group, so this is the whole answer)."""
-    locals_ = _local_partials(packed, sidx, bag.n_tables)
-    return _emulate_sparse_rejoin(locals_, packed, bag.n_tables)
+    """Asymmetric partials + emulated sparse rejoin, plus the symmetric
+    group a one-host plan (plain ``plan_asymmetric``) may fall back to."""
+    if not bag.plan.symmetric_tables:
+        locals_ = _local_partials(packed, sidx, bag.n_tables)
+        return _emulate_sparse_rejoin(locals_, packed, bag.n_tables)
+    return _full_lookup(bag, packed, sidx, rejoin="sparse")
 
 
 # --------------------------------------------------------------------------
@@ -262,7 +266,9 @@ def test_partition_property_random(hosts, cph, seed, n_tables):
     rng = np.random.default_rng(seed)
     rows = [int(rng.integers(8, 600)) for _ in range(n_tables)]
     seqs = [int(rng.integers(1, 3)) for _ in range(n_tables)]
-    wl = make_workload("prop", rows, dim=E, seqs=seqs, batch=16)
+    # 24 splits evenly over the 1-4 cores of a one-host plan, the only kind
+    # with a batch-split symmetric group
+    wl = make_workload("prop", rows, dim=E, seqs=seqs, batch=24)
     bag = _hier_bag(wl, hosts, cph)
     _assert_partition(bag.plan, wl, hosts, cph)
     tables = bag.init(jax.random.PRNGKey(seed % 97))

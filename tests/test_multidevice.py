@@ -8,46 +8,13 @@ from pathlib import Path
 
 SRC = str(Path(__file__).resolve().parent.parent / "src")
 
-# Feature-detect shim prepended to every subprocess: older jax releases have
-# no jax.sharding.AxisType / make_mesh(axis_types=...) / jax.shard_map, so the
-# test snippets (written against the modern API) fall back to the plain Mesh
-# constructor.  This intentionally does NOT delegate to repro.compat: compat
-# feature-detects the same jax attributes we are grafting here, so installing
-# its functions onto the jax namespace makes it call itself (recursion).
-_COMPAT_PREAMBLE = """
-import enum
-import jax, jax.sharding
-
-if not hasattr(jax.sharding, "AxisType"):
-    class _AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-    jax.sharding.AxisType = _AxisType
-    _orig_make_mesh = jax.make_mesh
-    jax.make_mesh = (
-        lambda axis_shapes, axis_names, *, axis_types=None:
-            _orig_make_mesh(tuple(axis_shapes), tuple(axis_names))
-    )
-
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
-    jax.shard_map = _shard_map
-"""
-
-
 def run_py(code: str, devices: int = 8, timeout: int = 900) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
-        [sys.executable, "-c", _COMPAT_PREAMBLE + textwrap.dedent(code)],
+        [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env,
     )
     assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-3000:]

@@ -209,3 +209,50 @@ def test_structural_validation_still_enforced():
         warnings.simplefilter("ignore")
         with pytest.raises(ValueError, match="use_kernels='fused'"):
             config_from_args(args)
+
+
+# ------------------------------------------------------------ exit status
+
+
+def _crash_forward(monkeypatch, kernels_only):
+    """Make the DLRM forward raise — on every executor, or only on the fused
+    kernel path (the XLA reference fallback then serves degraded)."""
+    import repro.launch.serve as serve
+    import repro.models.dlrm as dlrm
+    from repro import compat
+
+    real = dlrm.forward_packed
+
+    def forward(*args, use_kernels="fused", **kw):
+        if use_kernels or not kernels_only:
+            raise RuntimeError("injected step crash")
+        return real(*args, use_kernels=use_kernels, **kw)
+
+    monkeypatch.setattr(dlrm, "forward_packed", forward)
+    monkeypatch.setattr(compat, "enable_compilation_cache", lambda: "")
+    return serve
+
+
+_SMOKE_ARGV = ["--workload", "smoke", "--batch", "16", "--queries", "48",
+               "--distribution", "uniform"]
+
+
+@pytest.mark.parametrize("kernels_only", [False, True],
+                         ids=["every-path", "fused-only-degraded"])
+def test_step_crash_exits_nonzero(monkeypatch, capsys, kernels_only):
+    serve = _crash_forward(monkeypatch, kernels_only)
+    with pytest.raises(SystemExit) as exc:
+        serve.main(_SMOKE_ARGV)
+    assert exc.value.code == 1
+    out = capsys.readouterr().out
+    key = "degraded_batches" if kernels_only else "batch_failures"
+    assert "[serve] FAILED:" in out and key in out
+
+
+def test_clean_run_exits_zero(monkeypatch, capsys):
+    from repro import compat
+    from repro.launch.serve import main
+
+    monkeypatch.setattr(compat, "enable_compilation_cache", lambda: "")
+    main(_SMOKE_ARGV)  # returns: nothing failed, nothing degraded
+    assert "FAILED" not in capsys.readouterr().out
