@@ -6,7 +6,7 @@ Each query goes in as ``server.submit_request(payload)`` and comes back
 through a Future-style handle holding *that query's* logit; the engine's
 ``Batcher`` microbatches behind the scenes (plan -> pack -> fused executor
 -> owner-sharded rejoin on an 8-device forced-host mesh).  The latency
-tracker reports the P99/throughput trade-off per placement plan — the
+tracker reports P50/P99 and the batch fill per placement plan — the
 CPU-scale analogue of the paper's Table I measurement loop.
 
 A second phase runs the same engine under a *bounded* admission queue with
@@ -90,8 +90,7 @@ def main():
         logit0 = float(handles[0].result())
         s = srv.stats()
         print(f"{planner:>10s}: p50={s['p50_us']:8.0f}us p99={s['p99_us']:8.0f}us "
-              f"tps={s['tps']:8.0f} hedged={s['hedged_batches']} "
-              f"logit[0]={logit0:+.3f}")
+              f"batch_fill={s['batch_fill']:.2f} logit[0]={logit0:+.3f}")
 
     overload_demo(engine, wl, cfg, args)
     print("OK")

@@ -936,20 +936,14 @@ def _local_sym_lookup(
     return out
 
 
-def _fused_asym_lookup(
-    packed: PackedPlan, indices: jax.Array, *, n_tables: int
-) -> jax.Array:
-    """One schedule-driven pallas_call for all slots (kernels/embedding_multi)."""
-    from repro.kernels.embedding_multi import (
-        multi_embedding_bag_dense,
-        multi_embedding_bag_ragged,
-    )
-
-    _, b, _ = indices.shape
-    e = packed.chunk_data.shape[-1]
-    interp = compat.pallas_interpret()
-
-    # vectorized slot preprocessing: (S, B, s) pre-clipped local indices
+def _fused_local_indices(
+    packed: PackedPlan, indices: jax.Array
+) -> tuple[jax.Array, jax.Array | None]:
+    """Vectorized slot preprocessing for the fused kernel: the (S, B, s)
+    local row of each slot's lookups, with the layout's sentinel where the
+    lookup is not this slot's (dense: the pad row; ragged: -1, which matches
+    no row-block window), and, when the pack carries a residency cache, the
+    cache position of each lookup (-1 = streamed)."""
     ti = packed.slot_table  # (S,)
     idx = jnp.take(indices, jnp.maximum(ti, 0), axis=0)  # (S, B, s)
     local = idx - packed.slot_offset[:, None, None]
@@ -959,30 +953,47 @@ def _fused_asym_lookup(
         & (local < packed.slot_rows[:, None, None])
         & (ti >= 0)[:, None, None]
     )
-    valid = valid & _replica_bmask(packed, b)[:, :, None]
-
+    valid = valid & _replica_bmask(packed, indices.shape[1])[:, :, None]
     if packed.layout == "dense":
         rpad = packed.chunk_data.shape[-2] - 1
-        lidx = jnp.where(valid, local, rpad).astype(jnp.int32)
+        return jnp.where(valid, local, rpad).astype(jnp.int32), None
+    lidx = jnp.where(valid, local, -1).astype(jnp.int32)
+    if not packed.cache_rows:
+        return lidx, None
+    # hot/cold split through the packed remap: cache-resident rows leave the
+    # streaming index tensor and arrive as cache positions.
+    trash = packed.cache_remap.shape[0] - 1  # remap[trash] == -1
+    g = jnp.where(valid, packed.slot_row_start[:, None, None] + local, trash)
+    hidx = jnp.take(packed.cache_remap, g).astype(jnp.int32)
+    return jnp.where(hidx >= 0, -1, lidx), hidx
+
+
+def _fused_asym_lookup(
+    packed: PackedPlan, indices: jax.Array, *, n_tables: int
+) -> jax.Array:
+    """One schedule-driven pallas_call for all slots (kernels/embedding_multi).
+
+    The index preparation in front of the kernel carries the
+    ``lookup_prep`` name scope; the kernel call stays outside it."""
+    from repro.kernels.embedding_multi import (
+        multi_embedding_bag_dense,
+        multi_embedding_bag_ragged,
+    )
+
+    _, b, _ = indices.shape
+    e = packed.chunk_data.shape[-1]
+    interp = compat.pallas_interpret()
+    ti = packed.slot_table  # (S,)
+    with jax.named_scope("lookup_prep"):
+        lidx, hidx = _fused_local_indices(packed, indices)
+
+    if packed.layout == "dense":
         pooled = multi_embedding_bag_dense(
             packed.chunk_data, lidx, interpret=interp
         )  # (S, B, E) f32
     elif packed.step_slot.shape[-1] == 0:
         pooled = jnp.zeros((ti.shape[0], b, e), jnp.float32)
     else:
-        # ragged: -1 sentinel (matches no row-block window in the kernel)
-        lidx = jnp.where(valid, local, -1).astype(jnp.int32)
-        cache = hidx = None
-        if packed.cache_rows:
-            # hot/cold split through the packed remap: cache-resident rows
-            # leave the streaming index tensor and arrive as cache positions.
-            trash = packed.cache_remap.shape[0] - 1  # remap[trash] == -1
-            g = jnp.where(
-                valid, packed.slot_row_start[:, None, None] + local, trash
-            )
-            hidx = jnp.take(packed.cache_remap, g).astype(jnp.int32)
-            lidx = jnp.where(hidx >= 0, -1, lidx)
-            cache = packed.cache_data
         pooled = multi_embedding_bag_ragged(
             packed.chunk_data[:-1],  # drop the shared zero row: block_r-tiled
             lidx,
@@ -994,7 +1005,7 @@ def _fused_asym_lookup(
             block_b=packed.block_b or None,
             interpret=interp,
             unique_cap=packed.unique_cap,
-            cache=cache,
+            cache=packed.cache_data if hidx is not None else None,
             hidx=hidx,
             # kernel_path is static aux: an all-onehot pack compiles the
             # exact pre-kernel-path graph (no selector prefetch at all).

@@ -235,8 +235,14 @@ def dlrm_step_maker(cfg, params):
     tables and the tower weights are arguments of the jitted forward, not
     constants baked into it, so the executable stays small enough for the
     compile cache.  ``step.lower(batch)`` lowers that forward for a
-    ``{"dense", "indices"}`` batch."""
+    ``{"dense", "indices"}`` batch.
+
+    Each call opens the host spans ``repro.stage`` (the payloads stacked
+    into ``dense`` and ``indices``), ``repro.dispatch`` (the jitted call
+    until it returns), ``repro.wait`` (``block_until_ready``) and
+    ``repro.fetch`` (the output copied to the host)."""
     import jax
+    from jax.profiler import TraceAnnotation
 
     from repro.models.dlrm import forward_packed
 
@@ -252,12 +258,17 @@ def dlrm_step_maker(cfg, params):
             )
 
         def step(payloads):
-            dense = np.stack([q["dense"] for q in payloads])
-            idx = np.stack([q["indices"] for q in payloads], axis=1)
+            with TraceAnnotation("repro.stage"):
+                dense = np.stack([q["dense"] for q in payloads])
+                idx = np.stack([q["indices"] for q in payloads], axis=1)
             batch = {"dense": dense, "indices": idx}
-            return np.asarray(
-                jax.block_until_ready(infer(engine.packed, mlp, batch))
-            )
+            # the argument copy to the device, then the enqueue
+            with TraceAnnotation("repro.dispatch"):
+                out = infer(engine.packed, mlp, batch)
+            with TraceAnnotation("repro.wait"):
+                out = jax.block_until_ready(out)
+            with TraceAnnotation("repro.fetch"):
+                return np.asarray(out)
 
         step.lower = lambda batch: infer.lower(engine.packed, mlp, batch)
         return step
@@ -378,7 +389,7 @@ def main(argv=None):
             print(f"[serve] WARNING: {len(unserved)} queries left unserved")
         s = srv.stats()
         print(f"[serve] dist={label:8s} p50={_fmt_us(s['p50_us'])} "
-              f"p99={_fmt_us(s['p99_us'])} tps={s['tps']:9.0f}")
+              f"p99={_fmt_us(s['p99_us'])}")
         _print_robustness(s)
         faults += [f"{label}:{f}" for f in serving_faults(s, len(unserved))]
         if failed:
@@ -435,7 +446,7 @@ def _serve_drift(args, wl, schedule, engine, make_step, split, *, n_dense):
         print(f"[serve] WARNING: {len(unserved)} queries left unserved")
     s = srv.stats()
     line = (f"[serve] drift p50={_fmt_us(s['p50_us'])} "
-            f"p99={_fmt_us(s['p99_us'])} tps={s['tps']:9.0f}")
+            f"p99={_fmt_us(s['p99_us'])}")
     if "replan" in s:
         r = s["replan"]
         line += (f" replans={r['replans']} parity_failures="

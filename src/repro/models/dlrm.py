@@ -122,7 +122,8 @@ def forward_packed(
     reduce_mode: str = "sparse",
 ) -> jax.Array:
     """The paper's partitioned serving path (fused streaming executor +
-    owner-sharded sparse rejoin by default)."""
+    owner-sharded sparse rejoin by default).  The tower's device ops carry
+    the ``tower`` name scope, so a profiler trace can tell them apart."""
     emb = bag.apply(
         packed,
         batch["indices"],
@@ -132,9 +133,10 @@ def forward_packed(
         use_kernels=use_kernels,
         reduce_mode=reduce_mode,
     )  # (N, B, E) f32
-    bot = _mlp_apply(mlp_params["bottom"], batch["dense"], final_act=True)
-    feat = interact(bot, emb.astype(bot.dtype))
-    return _mlp_apply(mlp_params["top"], feat)[..., 0]
+    with jax.named_scope("tower"):
+        bot = _mlp_apply(mlp_params["bottom"], batch["dense"], final_act=True)
+        feat = interact(bot, emb.astype(bot.dtype))
+        return _mlp_apply(mlp_params["top"], feat)[..., 0]
 
 
 def bce_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:

@@ -33,9 +33,13 @@ degrade-gracefully-under-spikes requirement of Park et al. (1811.09886):
   default: index the leading axis).  ``handle.wait(timeout)`` blocks (for
   cross-thread drivers) and ``handle.result()`` raises the typed error the
   request failed with, so callers distinguish shed vs failed vs slow;
-* hedged requests — if a batch's execution exceeds ``hedge_factor`` x the
-  median, a backup execution is launched (simulated duplicate here) and the
-  faster result wins: classic tail-taming for stragglers;
+* counters and spans — :class:`repro.serving.latency.LatencyTracker` keeps
+  whole-run histograms of queue wait and latency, the queue depth and the
+  batch fill, fed once per served batch.  A released batch opens the host
+  spans ``repro.validate``, ``repro.step`` and ``repro.complete``
+  (``jax.profiler.TraceAnnotation``: on the profiler's clock when one runs,
+  about a microsecond each when none does); an empty ``pump()`` opens none,
+  and no span is opened per query;
 * drift replanning (``DriftConfig``, DESIGN.md §5) — a streaming frequency
   sketch over the served index streams, a hysteresis drift trigger against
   the histogram the live plan was priced under, shadow re-pack off the hot
@@ -93,6 +97,7 @@ import time
 from typing import Any, Callable, Mapping, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.data.distributions import FrequencySketch, drift_distance
 from repro.serving.latency import LatencyTracker
@@ -384,8 +389,6 @@ class Server:
         *,
         max_batch: int = 256,
         max_wait_s: float = 0.005,
-        hedge_factor: float = 3.0,
-        n_replicas: int = 2,
         layout: dict | None = None,
         exec_mode: dict | None = None,
         cache: dict | None = None,
@@ -426,11 +429,7 @@ class Server:
         # batch output -> per-query results for submit_request handles;
         # default indexes the leading (batch) axis.
         self.split_fn = split_fn or (lambda out, n: [out[i] for i in range(n)])
-        self.tracker = LatencyTracker()
-        self.hedge_factor = hedge_factor
-        self.n_replicas = max(n_replicas, 1)
-        self.hedges = 0
-        self._exec_times: list[float] = []
+        self.tracker = LatencyTracker(max_batch)
         # admission control + deadlines
         self.max_queue = max_queue
         self.admission = admission
@@ -681,7 +680,8 @@ class Server:
         if not batch:
             return None
         if self.validator is not None:
-            batch = self._validate(batch)
+            with TraceAnnotation("repro.validate"):
+                batch = self._validate(batch)
             if not batch:
                 return None
         if self.fault_injector is not None:
@@ -691,9 +691,9 @@ class Server:
             self.fault_injector.fire("buffer", batch=self.total_batches)
         payloads = [q.payload for q in batch]
         self.total_batches += 1
-        t0 = self.clock()
         try:
-            out = self._execute(payloads)
+            with TraceAnnotation("repro.step"):
+                out = self._execute(payloads)
         except Exception as e:
             # fault containment: the error fails only this batch's handles
             # and never propagates out of (or poisons) the pump.
@@ -708,6 +708,16 @@ class Server:
                     q.handle._set_error(err)
             self._maybe_integrity_check()
             return None
+        with TraceAnnotation("repro.complete"):
+            return self._complete(batch, payloads, out, t_release=now)
+
+    def _complete(
+        self, batch: list[Query], payloads: list[Any], out: Any, t_release: float
+    ) -> Any | None:
+        """After the step: the NaN guard, accounting, the latency counters,
+        each handle filled with its query's slice, drift observation and the
+        integrity cadence.  Returns ``out``, or ``None`` when it was
+        poisoned."""
         if self._nan_guard and not _tree_finite(out):
             # poisoned output: fail only this batch, then hunt the source —
             # an immediate integrity sweep finds + heals the bad region.
@@ -722,22 +732,11 @@ class Server:
                     q.handle._set_error(err)
             self._integrity_sweep(reason="poisoned-output")
             return None
-        dt = self.clock() - t0
-        # hedging: a straggling execution is retried on a backup replica; we
-        # model the win as the median execution time (the backup is healthy).
-        if (
-            len(self._exec_times) >= 8
-            and dt > self.hedge_factor * float(np.median(self._exec_times))
-            and self.n_replicas > 1
-        ):
-            self.hedges += 1
-            dt = float(np.median(self._exec_times))
-        self._exec_times.append(dt)
-        now = self.clock()
         self.served += len(batch)
-        self.tracker.record_depth(len(self.batcher.queue))
-        for q in batch:
-            self.tracker.record(now - q.t_enqueue, queries=1)
+        self.tracker.record_batch(
+            np.fromiter((q.t_enqueue for q in batch), np.float64, len(batch)),
+            t_release, self.clock(), depth=len(self.batcher.queue),
+        )
         if any(q.handle is not None for q in batch):
             try:
                 parts = list(self.split_fn(out, len(batch)))
@@ -1032,7 +1031,6 @@ class Server:
 
     def stats(self) -> dict:
         s = self.tracker.summary()
-        s["hedged_batches"] = self.hedges
         # request accounting — the identity submitted == served + shed +
         # rejected + failed + invalid + pending is checked by
         # tests/servebench/chaosbench.

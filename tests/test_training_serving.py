@@ -178,23 +178,44 @@ def test_batcher_and_p99():
     assert max(calls) <= 8
 
 
-def test_hedging_tames_stragglers():
-    import time
+def test_latency_tracker_covers_the_whole_run():
+    """Ten batches of 1,000: every query counts, not the last 2,048."""
+    t = LatencyTracker(max_batch=1000)
+    for k in range(10):
+        enq = np.zeros(1000)
+        t.record_batch(enq, t_release=0.5e-3 * (k + 1), t_done=1e-3 * (k + 1), depth=k)
+    s = t.summary()
+    assert s["n"] == 10_000
+    assert s["p50_us"] == pytest.approx(5_000, rel=0.02)
+    assert s["p99_us"] == pytest.approx(10_000, rel=0.02)
+    assert s["queue_wait_p50_us"] == pytest.approx(2_500, rel=0.02)
+    assert s["queue_depth_mean"] == 4.5 and s["queue_depth_max"] == 9
+    assert s["batch_fill"] == 1.0
 
-    n = {"i": 0}
 
-    def step(payloads):
-        n["i"] += 1
-        if n["i"] % 10 == 0:
-            time.sleep(0.05)  # straggler
-        return payloads
+def test_queue_wait_never_exceeds_latency():
+    rng = np.random.default_rng(0)
+    t = LatencyTracker(max_batch=64)
+    for _ in range(50):
+        enq = np.sort(rng.uniform(0.0, 0.01, 64))
+        release = enq[-1] + rng.uniform(0.0, 1e-3)
+        t.record_batch(enq, release, release + rng.uniform(1e-4, 1e-2), depth=0)
+    s = t.summary()
+    for q in ("p50", "p99"):
+        assert 0 < s[f"queue_wait_{q}_us"] <= s[f"{q}_us"]
 
-    srv = Server(step, max_batch=4, max_wait_s=0.0, hedge_factor=3.0)
-    for i in range(200):
+
+def test_batch_fill_and_no_samples():
+    srv = Server(lambda p: list(p), max_batch=8, max_wait_s=10.0)
+    s = srv.stats()
+    assert s["batch_fill"] is None and s["queue_wait_p99_us"] is None
+    assert "queue_depth_mean" not in s and "tps" not in s
+    for i in range(12):
         srv.submit(i)
-        srv.pump()
-    srv.drain()
-    assert srv.hedges > 0
+    assert srv.drain() == []  # a batch of 8, then a forced one of 4
+    s = srv.stats()
+    assert srv.tracker.batches == 2 and s["batch_fill"] == 12 / 16
+    assert s["queue_wait_p99_us"] <= s["p99_us"]
 
 
 def test_latency_tracker_percentiles():
