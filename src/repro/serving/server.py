@@ -766,10 +766,12 @@ class Server:
         """Release-time input validation: count OOV/negative indices, apply
         the validator's sanitization, and (``reject`` mode) fail only the
         offending requests' handles.  A crashing validator fails the whole
-        batch as invalid rather than poisoning the pump."""
+        batch as invalid rather than poisoning the pump.  A validator that
+        rewrites a payload returns a new list: the very list it was handed
+        means every payload is unchanged."""
         payloads = [q.payload for q in batch]
         try:
-            payloads, counts, bad = self.validator(payloads)
+            out, counts, bad = self.validator(payloads)
         except Exception as e:
             self.invalid += len(batch)
             err = InvalidQueryError(f"validator failed on batch: {e!r}")
@@ -780,6 +782,8 @@ class Server:
             return []
         self.oov_indices += int(counts.get("oov", 0))
         self.negative_indices += int(counts.get("negative", 0))
+        if not bad and out is payloads:
+            return batch  # nothing failed, no payload rewritten
         live: list[Query] = []
         for i, q in enumerate(batch):
             if i in bad:
@@ -787,7 +791,7 @@ class Server:
                 if q.handle is not None:
                     q.handle._set_error(InvalidQueryError(bad[i]))
             else:
-                q.payload = payloads[i]
+                q.payload = out[i]
                 live.append(q)
         return live
 

@@ -101,6 +101,121 @@ def test_payload_validator_mapping_payloads():
     assert out[0]["indices"].tolist() == [[-1]]
 
 
+def _reference_validator(rows, mode):
+    """The per-query reference: one :meth:`IndexValidator.check` per payload,
+    the loop the batch validator replaces."""
+    v = IndexValidator(rows, mode)
+
+    def validate(payloads):
+        counts = {"oov": 0, "negative": 0}
+        bad = {}
+        out = list(payloads)
+        for i, p in enumerate(payloads):
+            sanitized, c = v.check(p["indices"] if isinstance(p, dict) else p)
+            counts["oov"] += c["oov"]
+            counts["negative"] += c["negative"]
+            if not c["invalid"]:
+                continue
+            if mode == "reject":
+                bad[i] = (
+                    f"{c['oov']} out-of-vocab + {c['negative']} negative "
+                    f"indices in query"
+                )
+            elif mode == "null-row":
+                out[i] = (
+                    dict(p, indices=sanitized) if isinstance(p, dict) else sanitized
+                )
+        return out, counts, bad
+
+    validate.mode = mode
+    return validate
+
+
+_ROWS = [10, 50, 7, 1000]
+
+
+def _planted_batch(rng, n, seq, form, shapes=None):
+    """``n`` payloads of ``(T, seq)`` int32 ids with planted OOV ids, ids
+    below -1 and -1 padding; ``shapes`` overrides the per-query seq."""
+    payloads = []
+    for q in range(n):
+        s = seq if shapes is None else shapes[q % len(shapes)]
+        idx = np.stack([rng.integers(0, r, s) for r in _ROWS]).astype(np.int32)
+        roll = rng.random(idx.shape)
+        idx[roll < 0.05] = -1
+        oov = roll > 0.985
+        idx[oov] = (np.array(_ROWS)[:, None] + rng.integers(0, 5, idx.shape))[oov]
+        idx[(roll > 0.05) & (roll < 0.065)] = -rng.integers(2, 9)
+        payloads.append(
+            {"dense": rng.normal(size=3), "indices": idx} if form == "mapping" else idx
+        )
+    return payloads
+
+
+def _assert_same_outcome(got, want, payloads):
+    out, counts, bad = got
+    ref_out, ref_counts, ref_bad = want
+    assert counts == ref_counts
+    assert bad == ref_bad
+    assert len(out) == len(ref_out) == len(payloads)
+    for o, r, p in zip(out, ref_out, payloads):
+        if r is p:
+            assert o is p  # unflagged payloads pass through as the same object
+            continue
+        if isinstance(r, dict):
+            assert o.keys() == r.keys() and o["dense"] is p["dense"]
+            o, r = o["indices"], r["indices"]
+        assert o.dtype == r.dtype and np.array_equal(o, r)
+
+
+@pytest.mark.parametrize("seq", [1, 3])
+@pytest.mark.parametrize("form", ["array", "mapping"])
+@pytest.mark.parametrize("mode", VALIDATION_MODES)
+def test_batch_validator_matches_per_query_check(mode, form, seq):
+    rng = np.random.default_rng([seq, len(form), len(mode)])
+    payloads = _planted_batch(rng, 300, seq, form)
+    originals = [
+        (p["indices"] if form == "mapping" else p).copy() for p in payloads
+    ]
+    got = payload_validator(_ROWS, mode)(payloads)
+    want = _reference_validator(_ROWS, mode)(payloads)
+    assert want[1]["oov"] and want[1]["negative"]  # the planted ids are there
+    if mode != "clip":
+        assert len(want[2]) + sum(o is not p for o, p in zip(want[0], payloads))
+    _assert_same_outcome(got, want, payloads)
+    for p, orig in zip(payloads, originals):  # inputs never written to
+        assert np.array_equal(p["indices"] if form == "mapping" else p, orig)
+
+
+@pytest.mark.parametrize("form", ["array", "mapping"])
+def test_clip_returns_the_same_payload_objects(form):
+    payloads = _planted_batch(np.random.default_rng(3), 64, 2, form)
+    before = [(p["indices"] if form == "mapping" else p).copy() for p in payloads]
+    out, counts, bad = payload_validator(_ROWS, "clip")(payloads)
+    assert out is payloads and not bad and counts["oov"]
+    for p, b in zip(payloads, before):
+        assert np.array_equal(p["indices"] if form == "mapping" else p, b)
+
+
+@pytest.mark.parametrize("mode", VALIDATION_MODES)
+def test_mixed_index_shapes_validate_per_shape(mode):
+    """A batch mixing index shapes (and an int64 query among int32 ones)
+    validates each query as the per-query check would, without raising."""
+    rng = np.random.default_rng(11)
+    payloads = _planted_batch(rng, 120, 1, "mapping", shapes=[1, 4])
+    payloads[7] = dict(payloads[7], indices=payloads[7]["indices"].astype(np.int64))
+    payloads[9] = dict(payloads[9], indices=np.zeros((len(_ROWS), 0), np.int32))
+    payloads[10] = np.zeros((0,), np.int32)  # empty: no table count to check
+    got = payload_validator(_ROWS, mode)(payloads)
+    _assert_same_outcome(got, _reference_validator(_ROWS, mode)(payloads), payloads)
+
+
+def test_batch_validator_table_count_mismatch_raises():
+    validate = payload_validator([10, 20], "clip")
+    with pytest.raises(ValueError):
+        validate([np.zeros((2, 1), np.int32), np.zeros((3, 1), np.int32)])
+
+
 # ------------------------------------------------------------ server wiring
 
 
@@ -203,6 +318,53 @@ def test_server_stats_counters_accumulate():
     assert v["mode"] == "clip"
     assert v["oov_indices"] == 1 and v["negative_indices"] == 1
     assert v["invalid_queries"] == 0  # clip never fails a request
+
+
+@pytest.mark.parametrize("mode", VALIDATION_MODES)
+def test_server_clean_batch_matches_per_query_validator(mode):
+    """On clean traffic the batch validator hands the step the very payloads
+    it was given, and the counters and every handle's result are those the
+    per-query reference gives."""
+    engine, wl = _engine(mode)
+    batches = _traffic(wl, 2, 8)
+    rows = [t.rows for t in wl.tables]
+
+    def run(**kw):
+        srv = engine.serve(max_wait_s=0.0, **kw)
+        handles = _drive(srv, wl, batches)
+        return srv.stats(), [np.asarray(h.result()) for h in handles]
+
+    s_batch, r_batch = run()
+    s_ref, r_ref = run(validator=_reference_validator(rows, mode))
+    assert s_batch["validation"] == s_ref["validation"]
+    assert s_batch["validation"]["oov_indices"] == 0
+    assert s_batch["served"] == s_ref["served"] == 16
+    assert s_batch["invalid"] == s_ref["invalid"] == 0
+    for x, y in zip(r_batch, r_ref, strict=True):
+        assert x.dtype == y.dtype and np.array_equal(x, y)
+
+    srv = engine.serve(max_wait_s=0.0)
+    for q in range(8):
+        srv.submit_request(batches[0][:, q])
+    released = srv.batcher.maybe_release(srv.clock(), force=True)
+    payloads = [q.payload for q in released]
+    assert srv._validate(released) is released
+    assert all(q.payload is p for q, p in zip(released, payloads))
+
+
+def test_server_validate_hands_on_rewritten_payloads():
+    engine, wl = _engine("null-row")
+    srv = engine.serve(max_wait_s=0.0)
+    idx = _traffic(wl, 1, 8)[0]
+    idx[1, 6, 0] = wl.tables[1].rows + 3
+    for q in range(8):
+        srv.submit_request(idx[:, q])
+    released = srv.batcher.maybe_release(srv.clock(), force=True)
+    payloads = [q.payload for q in released]
+    live = srv._validate(released)
+    assert len(live) == 8
+    assert live[6].payload[1, 0] == -1 and payloads[6][1, 0] == wl.tables[1].rows + 3
+    assert all(live[q].payload is payloads[q] for q in range(8) if q != 6)
 
 
 def test_idle_server_percentiles_are_none():
