@@ -116,6 +116,10 @@ class HardwareSpec:
     # link_bw >> host_link_bw is what makes host-local placement matter.
     host_link_bw: float = 12.5e9
     host_link_latency: float = 5e-6  # seconds per cross-host collective hop
+    # per-chip memory, for a platform whose cores each have their own chip
+    # (0 = not modelled: the paper's platforms share one HBM between cores)
+    hbm_bytes: int = 0  # HBM, bytes (the planner's capacity term)
+    smem_bytes: int = 0  # scalar memory, bytes (the fused schedule's room)
 
     @property
     def hbm_bw_per_core(self) -> float:
@@ -149,7 +153,8 @@ A100 = HardwareSpec(
     matmul_flops=312e12 / 108,
 )
 
-# TPU v5e: 1 core/chip, 197 TFLOP/s bf16 MXU, 819 GB/s HBM, 128 MB VMEM.
+# TPU v5e: 1 core/chip, 197 TFLOP/s bf16 MXU, 16 GB HBM at 819 GB/s, 128 MB
+# VMEM, 1 MiB SMEM (the compiler's own limit for a kernel's scalars).
 # l1_bytes is the planner's logical per-core budget (fused-kernel "L1"
 # chunks stream like GM ones); a table pinned whole by the symmetric
 # group's L1 kernels must also fit their PIN_VMEM_BYTES once padded to 128
@@ -165,6 +170,8 @@ TPU_V5E = HardwareSpec(
     vector_flops=4.0e12,
     matmul_flops=197e12,
     link_bw=50e9,
+    hbm_bytes=16_000_000_000,
+    smem_bytes=1 << 20,
 )
 
 HARDWARE: dict[str, HardwareSpec] = {

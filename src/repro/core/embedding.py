@@ -87,6 +87,7 @@ class PartitionedEmbeddingBag:
         cache_rows: int | None = None,
         kernel_path: str | None = None,
         tuning_cache=None,
+        mesh: jax.sharding.Mesh | None = None,
     ) -> PackedPlan:
         """Materialize the plan.  ``autotune=True`` sweeps the fused kernel's
         ``block_r``/``block_b`` first (recorded in ``plan.meta["tuning"]``).
@@ -99,7 +100,9 @@ class PartitionedEmbeddingBag:
         choice in ``plan.meta["kernel"]``) selects the dedup'd gather
         implementation; ``tuning_cache`` (a
         :class:`repro.core.autotune.TuningCache`) lets the autotune sweep
-        reuse prior picks for shape-identical plans."""
+        reuse prior picks for shape-identical plans.  With a ``mesh`` the
+        large per-core buffers go straight onto their cores' devices
+        (:func:`~repro.core.partition.pack_plan`)."""
         layout = layout or self.layout
         if freqs is None:
             freqs = self.planner_kwargs.get("freqs")
@@ -131,6 +134,7 @@ class PartitionedEmbeddingBag:
             unique_cap=unique_cap,
             cache_rows=cache_rows,
             kernel_path=kernel_path,
+            mesh=mesh,
         )
 
     def layout_summary(self) -> dict:

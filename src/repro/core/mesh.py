@@ -154,6 +154,7 @@ def plan_hierarchical(
     cache_target: float = 0.75,
     max_cache_rows: int = 4096,
     kernel_path: str = "auto",
+    itemsize: int = 4,
 ) -> Plan:
     """Hierarchical placement over a ``(hosts, n_cores // hosts)`` mesh.
 
@@ -192,7 +193,7 @@ def plan_hierarchical(
             lif_threshold=lif_threshold, lpt=lpt, rock_theta=rock_theta,
             shard_rocks=shard_rocks, freqs=freqs, dedup=dedup, cache=cache,
             cache_target=cache_target, max_cache_rows=max_cache_rows,
-            kernel_path=kernel_path,
+            kernel_path=kernel_path, itemsize=itemsize,
         )
         held = {a.table_idx for a in plan.assignments}
         plan.meta["mesh"] = {
@@ -314,6 +315,7 @@ def plan_hierarchical(
             sub_wl, cph, model,
             lif_threshold=lif_threshold, lpt=lpt, rock_theta=rock_theta,
             shard_rocks=True, freqs=sub_freqs, kernel_path="auto",
+            itemsize=itemsize,
         )
         for a in sub.assignments:
             assignments.append(

@@ -102,10 +102,11 @@ import numpy as np
 from jax import lax
 
 from repro import compat
+from repro.core import capacity
 from repro.core.cost_model import freq_of
 from repro.core.strategies import Plan, Strategy
 from repro.core.tables import TableSpec
-from repro.kernels.embedding_gm import embedding_bag_gm
+from repro.kernels.embedding_gm import embedding_bag_gm, embedding_bag_gm_gather
 from repro.kernels.embedding_l1 import embedding_bag_l1
 from repro.kernels.embedding_ub import embedding_bag_ub
 
@@ -128,11 +129,7 @@ STRATEGY_CODE: dict[Strategy, int] = {
 _CODE_STRATEGY = {v: k for k, v in STRATEGY_CODE.items()}
 
 _ROW_PAD = 8  # sublane-friendly row padding
-# ragged fused-kernel row-block candidates, largest first.  Bigger blocks
-# mean fewer steps, and the step schedule is scalar-prefetched into the
-# core's SMEM (1 MiB on v5e); smaller ones waste fewer padding rows.
-_RAGGED_BLOCK_RS = (512, 256, 128, 64)
-_RAGGED_PAD_SLACK = 1.125  # padded rows allowed per data row (incl. zero row)
+_align = capacity.align
 
 
 @jax.tree_util.register_pytree_node_class
@@ -151,6 +148,11 @@ class PackedPlan:
     ``rejoin_send[c, d]`` lists the tables core ``c`` sends to owner ``d``,
     ``rejoin_bucket[d]`` lists the tables core ``d`` owns, and
     ``rejoin_owned_pos[t]`` is table ``t``'s position in its owner's bucket.
+
+    Slots listed in ``gather_slot`` (the chunks beyond what the fused
+    kernel's SMEM schedule holds, ``capacity.split_gather``) have no region
+    and no steps in the ragged buffer: their rows sit in ``gather_data``,
+    from ``slot_row_start``, and the row gather looks them up.
     """
 
     # asymmetric slots
@@ -177,6 +179,9 @@ class PackedPlan:
     # hot-row residency cache (ragged layout; zero-sized when off)
     cache_data: Any = None  # (K, C, E) per-core resident hot-row mini-table
     cache_remap: Any = None  # (K, T+1) int32 buffer row -> cache pos, -1 cold
+    # row gather (ragged layout; zero-sized when every chunk streams)
+    gather_data: Any = None  # (K, L, sub, 128) lane-dense gathered rows
+    gather_slot: Any = None  # (K, G) int32 slots the gather serves, -1 pad
     # static layout descriptors (pytree aux data)
     layout: str = "ragged"
     block_r: int = 0  # fused-kernel row-block size (ragged)
@@ -195,7 +200,7 @@ class PackedPlan:
         "step_slot", "step_base", "step_block", "step_strategy",
         "step_kpath",
         "rejoin_send", "rejoin_owned_pos", "rejoin_bucket",
-        "sym_data", "cache_data", "cache_remap",
+        "sym_data", "cache_data", "cache_remap", "gather_data", "gather_slot",
     )
     # replicated across the core axis (everything else is core-sharded)
     _REPLICATED_FIELDS = (
@@ -234,21 +239,6 @@ class PackedPlan:
     @property
     def chunk_bytes(self) -> int:
         return int(np.prod(self.chunk_data.shape)) * self.chunk_data.dtype.itemsize
-
-
-def _align(n: int, mult: int) -> int:
-    return int(-(-n // mult) * mult)
-
-
-def _default_block_r(rows: Sequence[int]) -> int:
-    """Largest row-block whose per-chunk padding (each chunk plus its zero
-    row rounded up to a block) keeps the buffer within
-    ``_RAGGED_PAD_SLACK`` of its data rows."""
-    data = sum(r + 1 for r in rows)
-    for br in _RAGGED_BLOCK_RS[:-1]:
-        if sum(_align(r + 1, br) for r in rows) <= _RAGGED_PAD_SLACK * data:
-            return br
-    return _RAGGED_BLOCK_RS[-1]
 
 
 def place_packed(
@@ -425,6 +415,26 @@ def cache_plan_entries(
     return out
 
 
+def _per_core_array(build, k, shape, dtype, mesh, axis):
+    """A ``(k, *shape)`` array whose core-``c`` slice is ``build(c)``, a host
+    array.  With a ``mesh``, each slice goes straight onto the devices of
+    core ``c`` along ``axis``, one core at a time (a slice is freed once it
+    is on its devices); without one, the slices are stacked on the host."""
+    if mesh is None:
+        return jnp.asarray(np.stack([build(c) for c in range(k)]))
+    sharding = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(axis))
+    by_core: dict[int, list] = {}
+    for dev, idx in sharding.addressable_devices_indices_map((k, *shape)).items():
+        by_core.setdefault(idx[0].start or 0, []).append(dev)
+    shards = []
+    for c, devs in sorted(by_core.items()):
+        host = build(c)[None]
+        shards += [jax.device_put(host, d) for d in devs]
+        jax.block_until_ready(shards[-len(devs):])
+        del host
+    return jax.make_array_from_single_device_arrays((k, *shape), sharding, shards)
+
+
 def pack_plan(
     plan: Plan,
     tables: Sequence[TableSpec],
@@ -438,11 +448,23 @@ def pack_plan(
     unique_cap: int | None = None,
     cache_rows: int | None = None,
     kernel_path: str | None = None,
+    mesh: jax.sharding.Mesh | None = None,
+    axis: str = "model",
 ) -> PackedPlan:
     """Materialize a Plan into the packed executor layout.
 
-    ``table_data[i]`` is the (m_i, E) array for table i, or ``None`` for
-    abstract packing (zeros; used by tests/dry-runs that only need shapes).
+    ``table_data[i]`` is the (m_i, E) array for table i (host numpy, or a
+    jax array on any device), or ``None`` for abstract packing (zeros; used
+    by tests/dry-runs that only need shapes).  Rows are read a chunk at a
+    time into host memory, so no whole table is copied onto a device.
+
+    With a ``mesh`` whose ``axis`` has one device per core, the two large
+    per-core buffers (the fused kernel's ragged buffer and the row gather's
+    buffer) are built one core at a time on the host and put straight onto
+    that core's devices: neither the whole ``(K, ...)`` buffer nor a whole
+    table ever sits on one device, and the host holds about one core's
+    share beyond the source tables.  Without one they are built whole and
+    left where ``jnp.asarray`` puts them.
 
     ``layout="ragged"`` concatenates each core's chunks row-wise (the memory-
     proportional layout); ``layout="dense"`` pads every slot to the global
@@ -519,11 +541,16 @@ def pack_plan(
     max_slots = max(max_slots, 1)
     max_rows = max((a.rows for a in plan.assignments), default=1)
     max_rows_pad = _align(max_rows, _ROW_PAD)
+    np_dtype = jnp.dtype(dtype)
 
-    def tbl(i):
+    def rows_of(i, lo, hi):
+        """Rows ``[lo, hi)`` of table ``i`` on the host, in the pack's dtype."""
         if table_data is None:
-            return jnp.zeros((tables[i].rows, e), dtype)
-        return table_data[i].astype(dtype)
+            return np.zeros((hi - lo, e), np_dtype)
+        return np.asarray(table_data[i][lo:hi], dtype=np_dtype)
+
+    if mesh is not None and dict(mesh.shape).get(axis) != k:
+        mesh = None  # a simulated plan: nothing to place core by core
 
     slot_table = -np.ones((k, max_slots), np.int32)
     slot_offset = np.zeros((k, max_slots), np.int32)
@@ -555,7 +582,7 @@ def pack_plan(
             for s_i in range(max_slots):
                 if s_i < len(assigns):
                     a = assigns[s_i]
-                    chunk = tbl(a.table_idx)[a.row_offset : a.row_offset + a.rows]
+                    chunk = rows_of(a.table_idx, a.row_offset, a.row_offset + a.rows)
                     pad = max_rows_pad + 1 - chunk.shape[0]
                     chunk = jnp.pad(chunk, ((0, pad), (0, 0)))
                 else:
@@ -570,15 +597,24 @@ def pack_plan(
         step_kpath = np.zeros((k, 0), np.int32)
         cache_data = jnp.zeros((k, 0, e), dtype)
         cache_remap = jnp.zeros((k, 1), jnp.int32)
+        gather_data = jnp.zeros((k, 0, 1, capacity.LANES), dtype)
+        gather_slot = np.zeros((k, 0), np.int32)
         br = 0
         slot_window = 0
         n_pad_steps = 0
+        gathered = set()
     else:
         # ragged: per core, concatenate chunks row-wise; each chunk's region
         # is padded to a block_r multiple (>= 1 zero row after the data, the
         # slot's redirect target), so the fused kernel's row-blocks tile it.
-        br = block_r or _default_block_r([a.rows for a in plan.assignments])
+        br = block_r or capacity.default_block_r([a.rows for a in plan.assignments])
         br = max(_align(br, _ROW_PAD), _ROW_PAD)
+        # chunks beyond what the fused schedule holds go to the row gather
+        # (the planner priced the same split at the default block_r)
+        gathered = capacity.split_gather(
+            {c: [a.rows for a in per_core[c]] for c in range(k)}, br,
+            (plan.meta.get("capacity") or {}).get("step_limit"),
+        )
         # per-strategy step schedule: slots grouped by strategy code (then
         # ascending size) so every strategy's steps form one contiguous run —
         # L1-resident, GM-streamed, and UB one-hot slots all execute from the
@@ -602,6 +638,8 @@ def pack_plan(
             core_steps: list[tuple[int, int, int, int, int]] = []
             for s_i in core_order[core]:
                 a = per_core[core][s_i]
+                if (core, s_i) in gathered:
+                    continue
                 alloc = _align(a.rows + 1, br)
                 slot_row_start[core, s_i] = cur
                 code = STRATEGY_CODE[a.strategy]
@@ -618,15 +656,49 @@ def pack_plan(
         # so the buffer ends at the largest core's own total.
         t_pad = _align(t_needed, br)
 
-        buf = np.zeros((k, t_pad + 1, e), jnp.dtype(dtype).name)
+        def fused_rows(core):
+            buf = np.zeros((t_pad + 1, e), np_dtype)
+            for s_i, a in enumerate(per_core[core]):
+                if (core, s_i) not in gathered:
+                    start = int(slot_row_start[core, s_i])
+                    buf[start : start + a.rows] = rows_of(
+                        a.table_idx, a.row_offset, a.row_offset + a.rows
+                    )
+            return buf
+
+        chunk_arr = _per_core_array(fused_rows, k, (t_pad + 1, e), np_dtype, mesh, axis)
+
+        # the row gather's buffer: each core's gathered chunks back to back,
+        # lane-dense (see capacity.gather_rows_per_block)
+        g_slots = {c: sorted(s_i for cc, s_i in gathered if cc == c) for c in range(k)}
+        n_g = max((len(v) for v in g_slots.values()), default=0)
+        gather_slot = -np.ones((k, n_g), np.int32)
+        g_rows = [0] * k
         for core in range(k):
-            for s_i, a in enumerate(per_core.get(core, [])):
-                start = int(slot_row_start[core, s_i])
-                chunk = np.asarray(
-                    tbl(a.table_idx)[a.row_offset : a.row_offset + a.rows]
-                )
-                buf[core, start : start + a.rows] = chunk
-        chunk_arr = jnp.asarray(buf)
+            for j, s_i in enumerate(g_slots[core]):
+                gather_slot[core, j] = s_i
+                slot_row_start[core, s_i] = g_rows[core]
+                g_rows[core] += per_core[core][s_i].rows
+        if n_g:
+            rpb = capacity.gather_rows_per_block(e, np_dtype.itemsize)
+            g_shape = (-(-max(g_rows) // rpb), rpb * e // capacity.LANES,
+                       capacity.LANES)
+
+            def gather_rows(core):
+                buf = np.zeros((g_shape[0] * rpb, e), np_dtype)
+                for s_i in g_slots[core]:
+                    a = per_core[core][s_i]
+                    start = int(slot_row_start[core, s_i])
+                    buf[start : start + a.rows] = rows_of(
+                        a.table_idx, a.row_offset, a.row_offset + a.rows
+                    )
+                return buf.reshape(g_shape)
+
+            gather_data = _per_core_array(
+                gather_rows, k, g_shape, np_dtype, mesh, axis
+            )
+        else:
+            gather_data = jnp.zeros((k, 0, 1, capacity.LANES), dtype)
 
         if cache_rows:
             # residency-cache carve: copy each core's top-mass rows into the
@@ -637,6 +709,11 @@ def pack_plan(
             # candidates — clamp to the realized carve so zero rows are
             # never allocated or charged against the kernel's VMEM budget.
             entries = cache_plan_entries(plan, tables, freqs, cache_rows)
+            # rows of gathered chunks have no place in the ragged buffer
+            entries = {
+                c: [x for x in v if (c, x[0]) not in gathered]
+                for c, v in entries.items()
+            }
             realized = max((len(v) for v in entries.values()), default=0)
             cache_rows = min(cache_rows, realized)
         if cache_rows:
@@ -644,8 +721,8 @@ def pack_plan(
             cache_np = np.zeros((k, cache_pad, e), jnp.dtype(dtype).name)
             remap_np = -np.ones((k, t_pad + 1), np.int32)
             for core in range(k):
-                # one fancy-indexed fetch per (core, table): per-row tbl()
-                # round trips would be paid on every shadow re-pack.
+                # one fancy-indexed fetch per (core, table): per-row round
+                # trips would be paid on every shadow re-pack.
                 rows_by_table: dict[int, list[tuple[int, int]]] = {}
                 for p, (s_i, a, gid, _w) in enumerate(entries[core]):
                     row = int(slot_row_start[core, s_i]) + gid - a.row_offset
@@ -653,8 +730,11 @@ def pack_plan(
                     rows_by_table.setdefault(a.table_idx, []).append((p, gid))
                 for ti, pairs in rows_by_table.items():
                     pos = [p for p, _ in pairs]
-                    gids = [g for _, g in pairs]
-                    cache_np[core, pos] = np.asarray(tbl(ti)[jnp.asarray(gids)])
+                    gids = np.asarray([g for _, g in pairs])
+                    cache_np[core, pos] = (
+                        0 if table_data is None
+                        else np.asarray(table_data[ti][gids], np_dtype)
+                    )
             cache_data = jnp.asarray(cache_np)
             cache_remap = jnp.asarray(remap_np)
             cache_rows = cache_pad
@@ -703,6 +783,8 @@ def pack_plan(
     plan.meta["layout"] = {
         "kind": layout,
         "chunk_bytes": ragged_bytes,
+        "gather_bytes": int(np.prod(gather_data.shape)) * itemsize,
+        "gathered_chunks": len(gathered),
         "dense_bytes": dense_bytes,
         "bytes_vs_dense": ragged_bytes / max(dense_bytes, 1),
         "block_r": br,
@@ -711,7 +793,8 @@ def pack_plan(
         "n_steps": int(step_slot.shape[1]),
         "n_padding_steps": int(n_pad_steps),
         "padding_frac": 1.0
-        - sum(a.rows for a in plan.assignments)
+        - sum(a.rows for c, v in per_core.items() for s_i, a in enumerate(v)
+              if (c, s_i) not in gathered)
         * e * itemsize / max(ragged_bytes, 1),
     }
     cph = mesh_shape[1]
@@ -755,11 +838,10 @@ def pack_plan(
     if n_sym:
         msym = max(tables[i].rows for i in sym_idx)
         msym = _align(msym, _ROW_PAD)
-        sym_blocks = []
-        for i in sym_idx:
-            t = tbl(i)
-            sym_blocks.append(jnp.pad(t, ((0, msym + 1 - t.shape[0]), (0, 0))))
-        sym_data = jnp.stack(sym_blocks)
+        sym_np = np.zeros((n_sym, msym + 1, e), np_dtype)
+        for j, i in enumerate(sym_idx):
+            sym_np[j, : tables[i].rows] = rows_of(i, 0, tables[i].rows)
+        sym_data = jnp.asarray(sym_np)
     else:
         sym_data = jnp.zeros((0, 1, e), dtype)
 
@@ -783,6 +865,8 @@ def pack_plan(
         sym_data=sym_data,
         cache_data=cache_data,
         cache_remap=cache_remap,
+        gather_data=gather_data,
+        gather_slot=jnp.asarray(gather_slot),
         layout=layout,
         block_r=br,
         slot_window=slot_window,
@@ -842,21 +926,33 @@ def _local_asym_lookup(
     ``True`` is the retired per-slot scan spelling — it routes to the fused
     path.
     """
+    gather = _gather_lookup(
+        packed, indices, n_tables=n_tables, use_kernels=bool(use_kernels)
+    )
     if use_kernels:
-        return _fused_asym_lookup(packed, indices, n_tables=n_tables)
-    if packed.layout == "dense":
+        out = _fused_asym_lookup(packed, indices, n_tables=n_tables)
+    elif packed.layout == "dense":
         return _dense_asym_lookup(packed, indices, n_tables=n_tables)
+    else:
+        out = _xla_asym_lookup(packed, indices, n_tables=n_tables)
+    return out if gather is None else out + gather
 
+
+def _xla_asym_lookup(
+    packed: PackedPlan, indices: jax.Array, *, n_tables: int
+) -> jax.Array:
+    """XLA gather sweep over the ragged buffer's slots (the gathered ones
+    contribute zeros here)."""
     _, b, _ = indices.shape
     buffer = packed.chunk_data  # (T+1, E)
     zrow = buffer.shape[0] - 1  # shared trailing zero row
     bpos = jnp.arange(b, dtype=jnp.int32)
 
     def body(out, xs):
-        ti, off, rows, start, rep, nrep = xs
+        ti, off, rows, start, rep, nrep, fused = xs
         idx = jnp.take(indices, jnp.maximum(ti, 0), axis=0)  # (B, s)
         local = idx - off
-        valid = (idx >= 0) & (local >= 0) & (local < rows) & (ti >= 0)
+        valid = (idx >= 0) & (local >= 0) & (local < rows) & (ti >= 0) & fused
         # replica r of n serves the r-th contiguous batch 1/n-slice.
         bmask = (bpos * nrep) // b == rep
         valid = valid & bmask[:, None]
@@ -875,9 +971,74 @@ def _local_asym_lookup(
         packed.slot_row_start,
         packed.slot_rep,
         packed.slot_nrep,
+        ~_gathered_mask(packed),
     )
     out, _ = lax.scan(body, out0, xs)
     return out
+
+
+def _gathered_mask(packed: PackedPlan) -> jax.Array:
+    """(S,) bool: the slots the row gather serves."""
+    s = packed.slot_table.shape[0]
+    g = packed.gather_slot
+    return jnp.zeros(s + 1, bool).at[jnp.where(g >= 0, g, s)].set(True)[:s]
+
+
+def _gather_lookup(
+    packed: PackedPlan, indices: jax.Array, *, n_tables: int, use_kernels: bool
+) -> jax.Array | None:
+    """The gathered slots' partial (N, B, E) f32, or ``None`` when the pack
+    gathers nothing.  Each lookup in a gathered chunk's rows becomes a row
+    of the lane-dense ``gather_data``; every other lookup is ``-1`` and
+    gives exact zeros.  ``use_kernels`` runs the row-gather kernel, else
+    the same rows are read by XLA."""
+    if packed.gather_slot.shape[-1] == 0:
+        return None
+    _, b, _ = indices.shape
+    e = packed.chunk_data.shape[-1]
+    with jax.named_scope("lookup_gather"):
+        g = packed.gather_slot  # (G,)
+        s = jnp.maximum(g, 0)
+        ti = jnp.where(g >= 0, packed.slot_table[s], -1)
+        idx = jnp.take(indices, jnp.maximum(ti, 0), axis=0)  # (G, B, s)
+        local = idx - packed.slot_offset[s][:, None, None]
+        valid = (
+            (idx >= 0)
+            & (local >= 0)
+            & (local < packed.slot_rows[s][:, None, None])
+            & (ti >= 0)[:, None, None]
+        )
+        bpos = jnp.arange(b, dtype=jnp.int32)
+        bmask = (bpos[None, :] * packed.slot_nrep[s][:, None]) // b == (
+            packed.slot_rep[s][:, None]
+        )
+        valid = valid & bmask[:, :, None]
+        ridx = jnp.where(
+            valid, packed.slot_row_start[s][:, None, None] + local, -1
+        ).astype(jnp.int32)
+        if use_kernels:
+            pooled = embedding_bag_gm_gather(
+                packed.gather_data, ridx, e=e, interpret=compat.pallas_interpret()
+            )
+        else:
+            pooled = _gather_rows_xla(packed.gather_data, ridx, e)
+        out = jnp.zeros((n_tables, b, e), jnp.float32)
+        return out.at[jnp.maximum(ti, 0)].add(
+            jnp.where((ti >= 0)[:, None, None], pooled, 0.0)
+        )
+
+
+def _gather_rows_xla(lines: jax.Array, ridx: jax.Array, e: int) -> jax.Array:
+    """XLA twin of ``embedding_bag_gm_gather``: (L, sub, 128) buffer and
+    (G, B, s) rows (-1 = none) -> (G, B, E) f32 bags."""
+    n_lines = lines.shape[0] * lines.shape[1]
+    rpl = lines.shape[2] // e
+    r = jnp.maximum(ridx, 0)
+    line = jnp.take(lines.reshape(n_lines, lines.shape[2]), r // rpl, axis=0)
+    rows = jnp.take_along_axis(
+        line.reshape(*r.shape, rpl, e), (r % rpl)[..., None, None], axis=-2
+    )[..., 0, :]
+    return jnp.where((ridx >= 0)[..., None], rows, 0).astype(jnp.float32).sum(-2)
 
 
 def _dense_asym_lookup(
@@ -1013,9 +1174,13 @@ def _fused_asym_lookup(
                 packed.step_kpath if packed.kernel_path != "onehot" else None
             ),
         )  # (S, B, E) f32
+    keep = ti >= 0
+    if packed.gather_slot.shape[-1]:
+        # a gathered slot has no steps, so the kernel never writes its block
+        keep = keep & ~_gathered_mask(packed)
     out = jnp.zeros((n_tables, b, e), jnp.float32)
     return out.at[jnp.maximum(ti, 0)].add(
-        jnp.where((ti >= 0)[:, None, None], pooled, 0.0)
+        jnp.where(keep[:, None, None], pooled, 0.0)
     )
 
 

@@ -40,11 +40,14 @@ from typing import Mapping, Sequence
 
 import numpy as np
 
+from repro.core import capacity
+from repro.core.capacity import CapacityError
 from repro.core.cost_model import CostModel, core_times, freq_of, lif
 from repro.core.strategies import ChunkAssignment, Plan, Strategy
 from repro.core.tables import TableSpec, Workload
 
 __all__ = [
+    "CapacityError",
     "PLANNERS",
     "kernel_meta",
     "plan_asymmetric",
@@ -460,6 +463,105 @@ def plan_asymmetric(
     n_cores: int,
     model: CostModel,
     *,
+    itemsize: int = 4,
+    **options,
+) -> Plan:
+    """Paper §III-B greedy asymmetric planner (:func:`_greedy_asymmetric`,
+    whose keyword ``options`` it takes), held to each chip's HBM.
+
+    When the hardware gives an HBM size, the plan is priced in the bytes
+    each chip holds in the pack's layout (:mod:`repro.core.capacity`, rows
+    of ``itemsize`` bytes: the pack's dtype).  The symmetric group pads its
+    tables to the largest and any table may fall back to it, so a table
+    that would take a chip over its budget there, padded as often as the
+    workload has tables, is row-sharded over the cores up front (as
+    ``shard_rocks`` does): no table in the group can then take a chip over.
+    While a chip is still over its budget, the largest table not yet
+    row-sharded is row-sharded too and the greedy runs again; a plan that
+    cannot fit raises :class:`CapacityError`.  Each chip's chunks beyond
+    what the fused kernel's SMEM schedule holds go to the row gather,
+    largest first.  ``plan.meta["capacity"]`` records the budget and the
+    bytes per chip, ``plan.meta["gather"]`` the chunks the row gather
+    serves on each chip and their expected lookups per batch.
+    """
+    tables = workload.tables
+    budget = int(model.hardware.hbm_bytes * capacity.BUDGET_SHARE)
+    row_shard: set[int] = set()
+    if budget and n_cores > 1:
+        row_shard = {
+            i for i, t in enumerate(tables)
+            if len(tables) * (t.rows + 1) * t.dim * itemsize > budget
+        }
+    while True:
+        plan = _greedy_asymmetric(
+            workload, n_cores, model, row_shard=frozenset(row_shard), **options
+        )
+        cap, gather = _capacity(plan, workload, model.hardware, itemsize,
+                                options.get("freqs"))
+        cap["row_sharded"] = sorted(row_shard)
+        if cap["budget"] is None or max(cap["per_chip"]) <= cap["budget"]:
+            break
+        left = [i for i in range(len(tables)) if i not in row_shard]
+        if n_cores == 1 or not left:
+            chip = int(np.argmax(cap["own"]))
+            raise CapacityError(
+                chip, cap["per_chip"][chip], cap["budget"],
+                f"fused kernel {cap['fused_bytes']:,}, row gather "
+                f"{cap['gather_bytes']:,}, symmetric group {cap['sym_bytes']:,}"
+                f" over {n_cores} chip(s), tables row-sharded: "
+                f"{sorted(row_shard) or 'none'}",
+            )
+        row_shard.add(max(left, key=lambda i: (tables[i].rows, -i)))
+    plan.meta["capacity"] = cap
+    plan.meta["gather"] = gather
+    return plan
+
+
+def _capacity(plan: Plan, workload: Workload, hw, itemsize: int, freqs):
+    """The capacity record of a plan as the default pack lays it out, and
+    the row gather's chunks per chip with their expected lookups."""
+    tables, batch = workload.tables, workload.batch
+    per_core = plan.per_core()
+    rows = {c: [a.rows for a in per_core[c]] for c in range(plan.n_cores)}
+    block_r = capacity.default_block_r([a.rows for a in plan.assignments])
+    limit = capacity.step_limit(hw.smem_bytes)
+    gathered = capacity.split_gather(rows, block_r, limit)
+    sizes = capacity.chip_bytes(
+        rows, gathered, [tables[i].rows for i in plan.symmetric_tables],
+        e=tables[0].dim, itemsize=itemsize, block_r=block_r,
+    )
+    cap = {
+        "hbm_bytes": int(hw.hbm_bytes),
+        "budget": int(hw.hbm_bytes * capacity.BUDGET_SHARE) or None,
+        "itemsize": int(itemsize),
+        "step_limit": limit,
+        "block_r": block_r,
+        **sizes,
+    }
+    per_chip = []
+    for c in range(plan.n_cores):
+        recs = []
+        for s_i, a in enumerate(per_core[c]):
+            if (c, s_i) not in gathered:
+                continue
+            t = tables[a.table_idx]
+            f = _uniform_or(freq_of(freqs, a.table_idx), t.rows)
+            recs.append({
+                "table": a.table_idx, "row_offset": a.row_offset,
+                "rows": a.rows,
+                "lookups": batch * t.seq * f.range_mass(
+                    a.row_offset, a.row_offset + a.rows) / a.replicas,
+            })
+        per_chip.append(recs)
+    return cap, {"block_r": block_r, "step_limit": limit, "per_chip": per_chip}
+
+
+def _greedy_asymmetric(
+    workload: Workload,
+    n_cores: int,
+    model: CostModel,
+    *,
+    row_shard: frozenset = frozenset(),
     lif_threshold: float = 1.25,
     lpt: bool = False,
     replicate_hot: bool = False,
@@ -473,7 +575,8 @@ def plan_asymmetric(
     max_cache_rows: int = 4096,
     kernel_path: str = "auto",
 ) -> Plan:
-    """Paper §III-B greedy asymmetric planner.
+    """Paper §III-B greedy asymmetric planner.  ``row_shard`` names tables
+    to row-shard over the cores up front (the capacity term's choice).
 
     0. "big rock" pre-pass (our fix to the paper's greedy, see DESIGN.md):
        an un-chunkable table whose best single-core cost exceeds
@@ -550,32 +653,32 @@ def plan_asymmetric(
         pre_sym = [
             i
             for i, c in enumerate(costs)
-            if c > bound and i not in chunkable
+            if c > bound and i not in chunkable and i not in row_shard
         ]
-        if shard_rocks:
-            # TPU profile (DESIGN.md §2): on a pod every chip has its own
-            # HBM, so the paper's symmetric fallback (replicated tables)
-            # would multiply memory K x.  Rocks are instead row-sharded into
-            # K GM chunks — capacity sharding with the same offset-clip-psum
-            # execution (Megatron-style).
-            for i in pre_sym:
-                t = tables[i]
-                rows = -(-t.rows // n_cores)
-                off = 0
-                core = 0
-                while off < t.rows:
-                    r = min(rows, t.rows - off)
-                    strat, _ = model.best_strategy(
-                        dataclasses.replace(t, rows=r), batch, 1,
-                        (Strategy.GM, Strategy.GM_UB),
-                        freq_of(freqs, i), (off, off + r),
-                    )
-                    rock_chunks.append(
-                        ChunkAssignment(i, core % n_cores, off, r, strat)
-                    )
-                    off += r
-                    core += 1
-            pre_sym = []
+    # TPU profile (DESIGN.md §2): on a pod every chip has its own HBM, so
+    # the paper's symmetric fallback (replicated tables) would multiply
+    # memory K x.  Rocks (``shard_rocks``) and the tables the capacity term
+    # names are instead row-sharded into K GM chunks — capacity sharding
+    # with the same offset-clip-psum execution (Megatron-style).
+    for i in sorted(set(row_shard) | set(pre_sym if shard_rocks else ())):
+        t = tables[i]
+        rows = -(-t.rows // n_cores)
+        off = 0
+        core = 0
+        while off < t.rows:
+            r = min(rows, t.rows - off)
+            strat, _ = model.best_strategy(
+                dataclasses.replace(t, rows=r), batch, 1,
+                (Strategy.GM, Strategy.GM_UB),
+                freq_of(freqs, i), (off, off + r),
+            )
+            rock_chunks.append(
+                ChunkAssignment(i, core % n_cores, off, r, strat)
+            )
+            off += r
+            core += 1
+    if shard_rocks:
+        pre_sym = []
 
     placed_elsewhere = set(pre_sym) | {a.table_idx for a in rock_chunks}
     reduced = Workload(

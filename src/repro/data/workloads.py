@@ -8,8 +8,12 @@ stats — what matters downstream is the size distribution (paper Fig. 2).
 Following the paper, the "huge" user_id/item_id-class tables that do not fit
 the accelerator's global memory are excluded (Criteo's two largest fields).
 
-All tables: E=16, fp16, sum pooling; sequence length 1 except Huawei-25MB
-(1..172).  Default batch 8192 (paper Table I).
+All tables: E=16, sum pooling; sequence length 1 except Huawei-25MB
+(1..172).  Default batch 8192 (paper Table I).  The paper stores rows in
+fp16, and ``make_workload``'s ``TableSpec.dtype_bytes`` prices them so in
+the cost model; what is stored and served is the pack's dtype,
+``EngineConfig.dtype`` (float32 by default), which the planner's capacity
+term prices (``plan_asymmetric(itemsize=)``).
 """
 from __future__ import annotations
 

@@ -686,6 +686,9 @@ class InferenceEngine:
 
         dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
                  "float16": jnp.float16}[config.dtype]
+        if config.planner in ("asymmetric", "hierarchical"):
+            # the capacity term prices rows in the bytes the pack stores
+            planner_kwargs.setdefault("itemsize", jnp.dtype(dtype).itemsize)
         bag = PartitionedEmbeddingBag(
             workload,
             n_cores=n_cores,
@@ -707,19 +710,21 @@ class InferenceEngine:
             from repro.core.autotune import TuningCache
 
             tuning_cache = TuningCache()
-        packed = bag.pack(
-            table_data,
-            tuning_cache=tuning_cache,
-            **tuning.pack_kwargs(**config.tuning_options),
-        )
+        if mesh is None:
+            mesh = compat.make_mesh((1, jax.device_count()), ("data", "model"))
+        with jax.profiler.TraceAnnotation("repro.pack"):
+            packed = bag.pack(
+                table_data,
+                tuning_cache=tuning_cache,
+                mesh=mesh,
+                **tuning.pack_kwargs(**config.tuning_options),
+            )
 
         integrity = INTEGRITY_POLICIES.create(config.integrity)
         manifest = integrity.manifest(
             packed, bag.plan, **config.integrity_options
         )
 
-        if mesh is None:
-            mesh = compat.make_mesh((1, jax.device_count()), ("data", "model"))
         axis_size = dict(mesh.shape).get("model", 1)
         if n_cores != axis_size and not config.simulate:
             raise MeshShapeError(
@@ -1111,7 +1116,8 @@ class InferenceEngine:
             "layout": self.bag.layout_summary(),
             "config": self.config.to_dict(),
         }
-        for key in ("cache", "tuning", "distribution", "kernel", "mesh"):
+        for key in ("cache", "tuning", "distribution", "kernel", "mesh",
+                    "capacity", "gather"):
             if plan.meta.get(key) is not None:
                 out[key] = plan.meta[key]
         mesh_meta = plan.meta.get("mesh") or {}
@@ -1160,6 +1166,11 @@ class InferenceEngine:
         )
 
         recs = list(zip(plan.assignments, kern["per_chunk"], chunk_bytes))
+        gathered = {
+            (c, r["table"], r["row_offset"])
+            for c, chip in enumerate((plan.meta.get("gather") or {}).get("per_chip", []))
+            for r in chip
+        }
         lines: list[str] = []
         for h in range(hosts):
             host_recs = [r for r in recs if r[0].core // cph == h]
@@ -1182,13 +1193,42 @@ class InferenceEngine:
                 )
                 for a, rec, b in core_recs:
                     strat = getattr(a.strategy, "name", str(a.strategy))
+                    path = (
+                        "gather" if (core, a.table_idx, a.row_offset) in gathered
+                        else rec["path"]
+                    )
                     lines.append(
                         f"      chunk table={rec['table']} "
                         f"rows={rec['rows']} strategy={strat} "
-                        f"kernel={rec['path']} "
+                        f"kernel={path} "
                         f"(modeled onehot {rec['onehot_us']:.2f}us / "
                         f"sparse {rec['sparse_us']:.2f}us, lookup {b:,}B)"
                     )
+        return lines
+
+    @staticmethod
+    def _capacity_lines(s: dict) -> list[str]:
+        """Planned HBM per chip against the budget, and what the row gather
+        serves on each chip: its tables, rows and expected lookups per
+        batch."""
+        cap = s.get("capacity")
+        if not cap or not cap.get("budget"):
+            return []
+        lines = [
+            f"capacity: {max(cap['per_chip']):,}B planned per chip of a "
+            f"{cap['budget']:,}B budget ({cap['hbm_bytes']:,}B HBM): fused "
+            f"{cap['fused_bytes']:,}B, row gather {cap['gather_bytes']:,}B, "
+            f"symmetric {cap['sym_bytes']:,}B; row-sharded tables "
+            f"{cap['row_sharded'] or 'none'}"
+        ]
+        for c, recs in enumerate((s.get("gather") or {}).get("per_chip", [])):
+            if recs:
+                lines.append(
+                    f"  row gather chip {c}: tables "
+                    f"{sorted({r['table'] for r in recs})}, "
+                    f"{sum(r['rows'] for r in recs):,} rows, "
+                    f"{sum(r['lookups'] for r in recs):,.0f} lookups per batch"
+                )
         return lines
 
     def plan_report(self) -> str:
@@ -1232,6 +1272,7 @@ class InferenceEngine:
                 f"({kern['n_sparse']} sparse / {kern['n_onehot']} one-hot chunks)"
             )
             lines.extend(self._placement_tree(kern))
+        lines.extend(self._capacity_lines(s))
         lines.append(
             f"executor kernels={self.config.use_kernels} "
             f"reduce={self.config.reduce_mode} layout={self.config.layout}"

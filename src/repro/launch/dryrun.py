@@ -115,8 +115,11 @@ def lower_dlrm_cell(arch: str, shape_name: str, mesh, multi_pod: bool):
     packed_sh = jax.tree.map(
         lambda _: named(P()), packed_struct
     )
-    for f in ("chunk_data", "slot_table", "slot_offset", "slot_rows",
-              "slot_strategy", "slot_rep", "slot_nrep"):
+    from repro.core.partition import PackedPlan
+
+    for f in PackedPlan._ARRAY_FIELDS:
+        if f in PackedPlan._REPLICATED_FIELDS:
+            continue
         nd = getattr(packed_struct, f).ndim
         object.__setattr__ if False else setattr(
             packed_sh, f, named(P("model", *([None] * (nd - 1))))

@@ -18,8 +18,14 @@ from repro.data.workloads import WORKLOADS
 from repro.sim.ascend import SimParams, collect_measurements, strategy_time
 
 
-def small_model(l1_bytes=4096):
-    return analytic_model(dataclasses.replace(TPU_V5E, l1_bytes=l1_bytes))
+def small_model(l1_bytes=4096, **hw):
+    return analytic_model(dataclasses.replace(TPU_V5E, l1_bytes=l1_bytes, **hw))
+
+
+# the paper's planner alone: without a chip's HBM the capacity term is off
+# (a 50M-row f32 table padded 4x in the symmetric group would overflow a
+# v5e's budget and be row-sharded; tests/test_capacity.py covers that)
+PAPER_HW = {"hbm_bytes": 0}
 
 
 def test_cost_model_recovers_planted_betas():
@@ -100,14 +106,14 @@ def test_lif_fallback_triggers():
     """A pathologically imbalanced workload trips the LIF fallback."""
     cards = [100] * 3 + [50_000_000] * 1
     wl = make_workload("lif", cards, seqs=[1, 1, 1, 64], batch=8192)
-    model = small_model(1 << 20)
+    model = small_model(1 << 20, **PAPER_HW)
     plan = plan_asymmetric(wl, 8, model, lif_threshold=1.05)
     assert plan.symmetric_tables, "expected symmetric fallback"
 
 
 def test_chunking_only_when_beneficial():
     """Tables larger than L1 are chunked iff L1 speedup > n_chunks (paper rule)."""
-    model = small_model(1 << 20)  # 1 MiB
+    model = small_model(1 << 20, **PAPER_HW)  # 1 MiB
     # huge table: chunk count ~ GB/MB >> speedup -> not chunked
     wl = make_workload("big", [50_000_000], batch=8192)
     plan = plan_asymmetric(wl, 8, model)

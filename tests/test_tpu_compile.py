@@ -9,7 +9,7 @@ worker collects the same tests and only the one running this file loads the
 TPU library.  The operands are the engine's own packs of taobao at batch
 8192, built from abstract tables: one chip (every table in the fused kernel)
 and four chips (the symmetric group's per-table kernels on a quarter of the
-batch).
+batch); and the row gather at Criteo-1TB's per-chip shards.
 """
 import dataclasses
 import os
@@ -138,6 +138,28 @@ def test_symmetric_kernel(tpu, taobao, sym_pack, strategy, monkeypatch):
     idx = jax.ShapeDtypeStruct((BATCH // 4, 1), jnp.int32, sharding=tpu)
     monkeypatch.setattr(compat, "pallas_interpret", lambda: False)
     _compile(lambda t, i: _bag_with_strategy(t, i, code, True), table, idx)
+
+
+@pytest.mark.parametrize("rows", [47_000_000, 33_000_000])
+def test_row_gather_kernel(tpu, rows):
+    """The row gather at Criteo-1TB's per-chip shards (the 187M- and
+    130M-row tables over four chips), lane-dense, for a batch of 8192 over
+    three gathered tables: the buffer stays the operand as laid out (no
+    copy of it) and the kernel fits its SMEM and VMEM."""
+    from repro.core.capacity import gather_rows_per_block
+    from repro.kernels.embedding_gm import embedding_bag_gm_gather
+
+    rpb = gather_rows_per_block(E, 4)
+    lines = jax.ShapeDtypeStruct(
+        (-(-rows // rpb), rpb * E // 128, 128), jnp.float32, sharding=tpu
+    )
+    ridx = jax.ShapeDtypeStruct((3, BATCH, 1), jnp.int32, sharding=tpu)
+    compiled = _compile(
+        lambda l, r: embedding_bag_gm_gather(l, r, e=E), lines, ridx
+    )
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < rows * E * 4 * 1.01
+    assert mem.temp_size_in_bytes < 64 << 20
 
 
 def test_planner_never_pins_what_the_kernel_refuses(taobao):
